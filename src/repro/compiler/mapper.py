@@ -102,7 +102,8 @@ def plan_model(cfg: ArchConfig,
         esl_overlap=esl_overlap, esl_chunks=esl_chunks,
         seq_shard_kv=seq_shard_kv, kv_seq_axis=kv_seq_axis,
         remat=remat, scan_unroll=scan_unroll, use_kernels=use_kernels,
-        compute_dtype=compute_dtype, param_dtype=param_dtype, rules=rules)
+        compute_dtype=compute_dtype, param_dtype=param_dtype,
+        cache_dtype=compute_dtype, rules=rules)
 
 
 def _rule_table(tp_axis, dp_axes, fsdp_axes, moe_plan, mode) -> Dict[str, Any]:

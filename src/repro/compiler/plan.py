@@ -163,7 +163,9 @@ class KVPrecision:
     per-block scales are impossible with the decode path's incremental
     row-at-a-time writes (rescaling a whole resident block per token
     would re-read what paging exists to avoid), so the scale granularity
-    is one fp16 scalar per stored row per head.
+    is one bf16 scalar per stored row per head (bf16, not fp16: the TPU
+    v5e has no fp16 vector loads, so the paged kernel could not read an
+    fp16 scale tile).
     """
 
     requested: str                 # the knob value ("auto", "int8", ...)
@@ -192,9 +194,7 @@ class KVPrecision:
 def resolve_kv_precision(kv_dtype: str, cache_dtype: str) -> KVPrecision:
     """Map the ``kv_dtype`` knob onto a :class:`KVPrecision`.
 
-    ``fp8`` resolves to ``float8_e4m3fn``; availability under the
-    session's jax pin is the caller's check (the serving layer gates on
-    ``hasattr(jnp, "float8_e4m3fn")`` and falls back loudly).
+    ``fp8`` resolves to ``float8_e4m3fn``.
     """
     kd = (kv_dtype or "auto").lower()
     if kd == "auto":
@@ -206,9 +206,9 @@ def resolve_kv_precision(kv_dtype: str, cache_dtype: str) -> KVPrecision:
     if kd in ("float32", "fp32"):
         return KVPrecision("float32", "float32", None, 0.0)
     if kd == "int8":
-        return KVPrecision("int8", "int8", "float16", 127.0)
+        return KVPrecision("int8", "int8", "bfloat16", 127.0)
     if kd in ("fp8", "float8_e4m3fn"):
-        return KVPrecision("fp8", "float8_e4m3fn", "float16", 448.0)
+        return KVPrecision("fp8", "float8_e4m3fn", "bfloat16", 448.0)
     raise ValueError(f"unknown kv_dtype {kv_dtype!r} (expected auto, "
                      "float16, bfloat16, float32, int8 or fp8)")
 
@@ -238,10 +238,8 @@ class PhysicalPlan:
     use_kernels: bool = False        # pallas(interpret) vs jnp ref path
     compute_dtype: str = "bfloat16"
     param_dtype: str = "bfloat16"
-    # f32 by default: the CPU dry-run backend has no native bf16 dots and
-    # otherwise inserts whole-cache convert/copy churn that exists on no
-    # real TPU; the 2x cache-stream cost vs bf16 is called out in
-    # EXPERIMENTS.md §Roofline (TPU-native would halve the KV term).
+    # KV cache storage; the mapper sets it to the compute dtype (bf16
+    # when serving, f32 in the float32 reference plans)
     cache_dtype: str = "float32"
     logits_fp32: bool = True
     # logical-axis -> mesh-axes rule table (filled by the mapper)

@@ -1,54 +1,25 @@
-"""JAX version compatibility for the manual-collective layer.
+"""Mesh construction and ``shard_map`` for the manual-collective layer.
 
-The repo spans JAX releases on both sides of two API moves:
-
-* ``shard_map`` graduated from ``jax.experimental.shard_map`` to
-  ``jax.shard_map``, renaming ``check_rep`` -> ``check_vma`` on the way;
-* ``jax.make_mesh`` grew an ``axis_types`` keyword (explicit/auto axis
-  semantics) that older releases reject.
-
-Every mesh construction and every ``shard_map`` wrap in the repo goes
-through this module so the serving engine, the step builders and the
-multi-device tests run unmodified on either side.  The semantics we rely
-on (ppermute rings, grouped collectives, Auto axis types) are identical
-across the supported range — only the spelling moved.
+Every mesh and every ``shard_map`` wrap in the repo goes through this
+module.  ``jax.make_mesh`` defaults to Explicit axis types; the ring code
+(ppermute rings, grouped collectives inside ``shard_map``) is written
+against Auto axes, so the mesh asks for them.
 """
 from __future__ import annotations
 
 from typing import Sequence
 
 import jax
+from jax import shard_map
+from jax.sharding import AxisType
 
-try:  # newer jax: explicit axis types on the mesh
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    _AxisType = None
-
-if hasattr(jax, "shard_map"):  # jax >= 0.6 spelling
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` with the ``check_vma``/``check_rep`` rename folded."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_vma)
-    except TypeError:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
+__all__ = ["make_mesh", "shard_map"]
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               *, devices=None):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
+    """``jax.make_mesh`` with Auto axis types."""
     kw = {} if devices is None else {"devices": devices}
-    if _AxisType is not None:
-        try:
-            return jax.make_mesh(
-                tuple(axis_shapes), tuple(axis_names),
-                axis_types=(_AxisType.Auto,) * len(tuple(axis_names)), **kw)
-        except TypeError:  # pragma: no cover
-            pass
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(AxisType.Auto,) * len(names), **kw)

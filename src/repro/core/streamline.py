@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from repro.kernels.decode_attention.ops import (decode_attention,
                                                 paged_decode_attention,
                                                 resolve_paged_kernel)
+from repro.kernels.decode_attention.ref import gather_kv_pages
 from repro.kernels.gemv.ops import gemv, quantize_weight
 from repro.models.common import apply_norm, apply_rope
 
@@ -39,7 +40,7 @@ W_DTYPES = ("auto", "int8")
 
 
 def _mm(x2d: jax.Array, w: jax.Array, b: Optional[jax.Array], *,
-        use_kernels: bool, interpret: bool = True,
+        use_kernels: bool, interpret: Optional[bool] = None,
         quantize: bool = False) -> jax.Array:
     if quantize:
         # int8 weight stream: per-output-column absmax quantization, the
@@ -53,7 +54,7 @@ def _mm(x2d: jax.Array, w: jax.Array, b: Optional[jax.Array], *,
 
 def decode_layer(p: Params, x: jax.Array, cache: Dict[str, jax.Array],
                  positions: jax.Array, *, cfg, plan,
-                 use_kernels: bool = True, interpret: bool = True,
+                 use_kernels: bool = True, interpret: Optional[bool] = None,
                  block_table: Optional[jax.Array] = None,
                  paged_kernel: str = "auto",
                  w_dtype: str = "auto"
@@ -64,7 +65,7 @@ def decode_layer(p: Params, x: jax.Array, cache: Dict[str, jax.Array],
     Returns (y (B, D), new cache).  Weights in the mapper's stored layout.
 
     Paged mode (``block_table`` (B, T) given): cache k/v are the shared
-    block pool (N, bs, G, dh).  The new token's KV scatters into
+    block pool (N, G, bs, dh).  The new token's KV scatters into
     physical block ``table[b, pos // bs]`` at offset ``pos % bs`` — the
     serving engine's pool layout, tp-folded just like the weights (each
     rank holds its head shard of every block).  ``paged_kernel``:
@@ -114,7 +115,7 @@ def decode_layer(p: Params, x: jax.Array, cache: Dict[str, jax.Array],
     if block_table is not None:
         # pool scatter: one (G, dh) row per sequence; inactive slots all
         # target the null block 0 (don't-care, masked by valid length)
-        bs_blk = cache["k"].shape[1]
+        bs_blk = cache["k"].shape[2]
         blk = jnp.take_along_axis(block_table,
                                   (positions // bs_blk)[:, None],
                                   axis=1)[:, 0]
@@ -125,15 +126,16 @@ def decode_layer(p: Params, x: jax.Array, cache: Dict[str, jax.Array],
                                        cache["k_scale"].dtype)
             vq, vsc = quantize_kv_rows(v_new, cache["v"].dtype,
                                        cache["v_scale"].dtype)
-            kc = cache["k"].at[blk, off].set(kq)
-            vc = cache["v"].at[blk, off].set(vq)
-            ks = cache["k_scale"].at[blk, off].set(ksc)
-            vs = cache["v_scale"].at[blk, off].set(vsc)
+            kc = cache["k"].at[blk, :, off].set(kq)
+            vc = cache["v"].at[blk, :, off].set(vq)
+            ks = cache["k_scale"].at[blk, :, off].set(ksc)
+            vs = cache["v_scale"].at[blk, :, off].set(vsc)
         else:
-            kc = cache["k"].at[blk, off].set(k_new.astype(cache["k"].dtype))
-            vc = cache["v"].at[blk, off].set(v_new.astype(cache["v"].dtype))
-        mode = resolve_paged_kernel(plan, bs_blk, paged_kernel,
-                                    interpret=interpret)
+            kc = cache["k"].at[blk, :, off].set(
+                k_new.astype(cache["k"].dtype))
+            vc = cache["v"].at[blk, :, off].set(
+                v_new.astype(cache["v"].dtype))
+        mode = resolve_paged_kernel(plan, paged_kernel)
         if mode == "stream":
             # gather-free: the kernel's scalar-prefetched table resolves
             # each KV tile's pool address — the streamed chain never
@@ -144,14 +146,13 @@ def decode_layer(p: Params, x: jax.Array, cache: Dict[str, jax.Array],
                 use_pallas=use_kernels, interpret=interpret)
             attn_done = True
         else:
-            T = block_table.shape[1]
-            k_view = kc[block_table].reshape(B, T * bs_blk, *kc.shape[2:])
-            v_view = vc[block_table].reshape(B, T * bs_blk, *vc.shape[2:])
+            k_view = gather_kv_pages(kc, block_table)
+            v_view = gather_kv_pages(vc, block_table)
             if quantized:
-                k_view = k_view.astype(jnp.float32) * ks[
-                    block_table].reshape(B, T * bs_blk, kpr)[..., None]
-                v_view = v_view.astype(jnp.float32) * vs[
-                    block_table].reshape(B, T * bs_blk, kpr)[..., None]
+                k_view = k_view.astype(jnp.float32) * gather_kv_pages(
+                    ks, block_table)[..., None]
+                v_view = v_view.astype(jnp.float32) * gather_kv_pages(
+                    vs, block_table)[..., None]
             attn_done = False
     else:
         def upd(c, n, pos):
@@ -194,7 +195,7 @@ def chunk_prefill_layer(p: Params, x: jax.Array,
                         cache: Dict[str, jax.Array],
                         block_table: jax.Array, start: jax.Array,
                         n_valid: jax.Array, *, cfg, plan,
-                        use_kernels: bool = True, interpret: bool = True,
+                        use_kernels: bool = True, interpret: Optional[bool] = None,
                         paged_kernel: str = "auto"
                         ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One decoder layer over ONE prefill chunk, single device.
@@ -209,7 +210,7 @@ def chunk_prefill_layer(p: Params, x: jax.Array,
     causal prefix falls out of the kernel's length masking; nothing new
     is lowered for prefill.
 
-    x: (C, D) chunk activations; cache: {'k','v': (N, bs, G, dh)} the
+    x: (C, D) chunk activations; cache: {'k','v': (N, G, bs, dh)} the
     shared pool; block_table: (T,); start: absolute offset of the chunk;
     n_valid: valid rows (padded tail rows land in the null block 0).
     Returns (y (C, D), updated pool).
@@ -243,10 +244,8 @@ def chunk_prefill_layer(p: Params, x: jax.Array,
                             valid)
     vc = scatter_chunk_rows(cache["v"], v_new, block_table, positions,
                             valid)
-    bs_blk = kc.shape[1]
     lens = jnp.minimum(positions + 1, start + n_valid)
-    mode = resolve_paged_kernel(plan, bs_blk, paged_kernel,
-                                interpret=interpret)
+    mode = resolve_paged_kernel(plan, paged_kernel)
     if mode == "stream":
         tabs = jnp.broadcast_to(block_table[None],
                                 (C, block_table.shape[0]))
@@ -254,13 +253,10 @@ def chunk_prefill_layer(p: Params, x: jax.Array,
                                       use_pallas=use_kernels,
                                       interpret=interpret)
     else:
-        T = block_table.shape[0]
-        k_view = jnp.broadcast_to(
-            kc[block_table].reshape(1, T * bs_blk, *kc.shape[2:]),
-            (C, T * bs_blk) + kc.shape[2:])
-        v_view = jnp.broadcast_to(
-            vc[block_table].reshape(1, T * bs_blk, *vc.shape[2:]),
-            (C, T * bs_blk) + vc.shape[2:])
+        k_view = gather_kv_pages(kc, block_table[None])
+        v_view = gather_kv_pages(vc, block_table[None])
+        k_view = jnp.broadcast_to(k_view, (C,) + k_view.shape[1:])
+        v_view = jnp.broadcast_to(v_view, (C,) + v_view.shape[1:])
         attn = decode_attention(q, k_view, v_view, lens,
                                 use_pallas=use_kernels,
                                 interpret=interpret)
@@ -287,7 +283,7 @@ def chunk_prefill_layer(p: Params, x: jax.Array,
 
 def verify_layer(p: Params, x: jax.Array, cache: Dict[str, jax.Array],
                  block_tables: jax.Array, positions: jax.Array, *, cfg,
-                 plan, use_kernels: bool = True, interpret: bool = True,
+                 plan, use_kernels: bool = True, interpret: Optional[bool] = None,
                  paged_kernel: str = "auto", w_dtype: str = "auto"
                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One decoder layer over one speculative verify window.

@@ -17,11 +17,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -60,6 +63,16 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
                     jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
+def _head_row(scale_ref, g) -> jax.Array:
+    """Row ``g`` of a (G, bs) scale tile as a (1, bs) f32 vector.
+
+    Selected by a masked sum rather than a dynamic sublane slice: Mosaic
+    cannot prove a dynamic row of a packed 16-bit tile aligned."""
+    sc = scale_ref[...].astype(jnp.float32)
+    rows = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0)
+    return jnp.sum(jnp.where(rows == g, sc, 0.0), axis=0, keepdims=True)
+
+
 def _paged_decode_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
                          t_blocks: int, block_s: int, scale: float,
                          quantized: bool = False):
@@ -74,12 +87,14 @@ def _paged_decode_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
     read-then-scatter contract, so the pool is never copied to append one
     row.
 
-    ``quantized``: the pool tiles are int8/fp8 and ``ks_ref/vs_ref``
-    carry one absmax scale per (row, kv head); dequantization happens
-    HERE, inside the tile loop right after the VMEM load, so fp KV
-    values never round-trip through HBM — the stream stays at the
-    quantized byte width end to end.  The folded new token's K/V stays
-    full precision (it is a fresh activation, not pool storage)."""
+    ``quantized``: the pool tiles are int8/fp8 and ``ks_ref/vs_ref`` hold
+    the (G, bs) absmax scales of the tile's block; row ``g`` is this kv
+    head's.  Dequantization happens HERE, inside the tile loop, so fp KV
+    values never round-trip through HBM.  A row scale is constant along
+    ``dh``, so it multiplies the score column (K) and the probability
+    column (V) instead of the tile — no (bs, 1) relayout.  The folded new
+    token's K/V stays full precision (it is a fresh activation, not pool
+    storage)."""
     rest = list(rest)
     if quantized:
         ks_ref, vs_ref = rest[0], rest[1]
@@ -90,6 +105,7 @@ def _paged_decode_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
+    g = pl.program_id(1)
     t = pl.program_id(2)
 
     @pl.when(t == 0)
@@ -99,13 +115,12 @@ def _paged_decode_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0].astype(jnp.float32) * scale            # (gs, dh)
-    k = k_ref[0, :, 0].astype(jnp.float32)              # (block_s, dh)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    if quantized:
-        k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-        v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
+    k = k_ref[...].astype(jnp.float32)                  # (block_s, dh)
+    v = v_ref[...].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
+    if quantized:
+        s = s * _head_row(ks_ref, g)
     length = len_ref[b]
     pos = t * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(pos < length, s, -1e30)
@@ -115,6 +130,8 @@ def _paged_decode_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, -1, keepdims=True)
+    if quantized:
+        p = p * _head_row(vs_ref, g)
     pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
     acc_ref[...] = acc_ref[...] * corr + pv
@@ -147,23 +164,30 @@ def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
                                   v_new: jax.Array = None,
                                   k_scale: jax.Array = None,
                                   v_scale: jax.Array = None,
-                                  interpret: bool = True) -> jax.Array:
-    """q: (B,H,dh); k_pages,v_pages: (N,bs,G,dh) shared pool with H = G*gs;
-    block_tables: (B,T) physical block per logical block; lengths: (B,).
-    Returns (B,H,dh).  The block table rides scalar prefetch so each KV
-    tile's pool address is resolved before its DMA issues.
+                                  interpret: Optional[bool] = None
+                                  ) -> jax.Array:
+    """q: (B,H,dh); k_pages,v_pages: (N,G,bs,dh) head-major shared pool
+    with H = G*gs; block_tables: (B,T) physical block per logical block;
+    lengths: (B,).  Returns (B,H,dh).  The block table rides scalar
+    prefetch so each KV tile's pool address is resolved before its DMA
+    issues.
+
+    Head-major is what makes the kernel compile: a KV tile is the whole
+    trailing (bs, dh) plane of one (block, head), so Mosaic's rule that a
+    block's last two dims be (8, 128)-aligned or span the array holds for
+    any ``bs`` and ``d_head`` (64-wide heads included).
 
     ``k_new/v_new`` ((B,G,dh), both or neither): the current token's K/V,
     folded into the softmax carry *after* the streamed pool tiles — used
     by the decode path that reads the cache pre-update and lets the
     caller scatter the new row into the pool afterwards.
 
-    ``k_scale/v_scale`` ((N,bs,G), both or neither): the quantized
-    pool's absmax scale side-arrays; their tiles ride the SAME
-    block-table indirection as the value tiles and dequantization runs
-    inside the tile loop."""
+    ``k_scale/v_scale`` ((N,G,bs), both or neither): the quantized
+    pool's absmax scale side-arrays; the whole (G, bs) scale plane of a
+    block rides the SAME block-table indirection as the value tiles and
+    dequantization runs inside the tile loop."""
     B, H, dh = q.shape
-    N, bs, G, _ = k_pages.shape
+    N, G, bs, _ = k_pages.shape
     T = block_tables.shape[1]
     assert H % G == 0, (H, G)
     assert (k_new is None) == (v_new is None)
@@ -174,18 +198,17 @@ def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
     kernel = functools.partial(_paged_decode_kernel, t_blocks=T, block_s=bs,
                                scale=1.0 / math.sqrt(dh),
                                quantized=k_scale is not None)
+    kv_spec = pl.BlockSpec((None, None, bs, dh),
+                           lambda b, g, t, lens, tbl: (tbl[b, t], g, 0, 0))
     in_specs = [
         pl.BlockSpec((1, gs, dh),
                      lambda b, g, t, lens, tbl: (b * G + g, 0, 0)),
-        pl.BlockSpec((1, bs, 1, dh),
-                     lambda b, g, t, lens, tbl: (tbl[b, t], 0, g, 0)),
-        pl.BlockSpec((1, bs, 1, dh),
-                     lambda b, g, t, lens, tbl: (tbl[b, t], 0, g, 0)),
+        kv_spec, kv_spec,
     ]
     operands = [lengths, block_tables, qg, k_pages, v_pages]
     if k_scale is not None:
         scale_spec = pl.BlockSpec(
-            (1, bs, 1), lambda b, g, t, lens, tbl: (tbl[b, t], 0, g))
+            (None, G, bs), lambda b, g, t, lens, tbl: (tbl[b, t], 0, 0))
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
     if k_new is not None:
@@ -210,14 +233,15 @@ def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * G, gs, dh), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
+        name="paged_decode_attention",
     )(*operands)
     return out.reshape(B, H, dh)
 
 
 def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                             lengths: jax.Array, *, block_s: int = 512,
-                            interpret: bool = True) -> jax.Array:
+                            interpret: Optional[bool] = None) -> jax.Array:
     """q: (B,H,dh); k,v: (B,S,G,dh) with H = G*gs (block-regular GQA);
     lengths: (B,) valid cache length.  Returns (B,H,dh)."""
     B, H, dh = q.shape
@@ -248,6 +272,7 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((gs, 1), jnp.float32),
             pltpu.VMEM((gs, dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
+        name="decode_attention",
     )(lengths, qg, k, v)
     return out.reshape(B, H, dh)

@@ -13,47 +13,29 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.decode_attention.decode_attention import (
     decode_attention_pallas, paged_decode_attention_pallas)
-from repro.kernels.decode_attention.ref import (
-    decode_attention_ref, gather_kv_pages)
+from repro.kernels.decode_attention.ref import (decode_attention_ref,
+                                                paged_decode_attention_ref)
 
 LANE = 128
 VMEM_BUDGET = 32 * 2 ** 20
 
 
-def default_interpret() -> bool:
-    """Pallas interpret mode off-TPU (CPU CI runs the same kernel code)."""
-    return jax.default_backend() != "tpu"
-
-
-def paged_stream_supported(plan, block_size: Optional[int] = None,
-                           interpret: Optional[bool] = None) -> bool:
+def paged_stream_supported(plan) -> bool:
     """True when paged decode can stream through the Pallas kernel.
 
-    Two conditions: the plan's stored GQA layout must be block-regular
-    (the kernel maps q head ``h`` to kv head ``h // gs`` with no
-    per-head gather), and — compiled on TPU only — the KV tile must be
-    LANE-aligned (``block_size`` and ``d_head`` multiples of 128).
-    ``interpret=None`` derives the mode from the backend, matching what
-    the kernel call will actually do.  Resolving eligibility *here*
-    keeps the dispatch honest: a misaligned "stream" request resolves
-    to gather up front (and is accounted as gather) instead of
-    silently falling back inside the kernel wrapper while the engine
-    reports streamed statistics."""
+    The one condition is the plan's stored GQA layout: it must be
+    block-regular (the kernel maps q head ``h`` to kv head ``h // gs``
+    with no per-head gather).  Tile alignment is not a condition: the
+    head-major pool hands the kernel whole (bs, dh) planes, which Mosaic
+    accepts for any block size and head width."""
     a = plan.attn
-    if a is None or not a.block_regular:
-        return False
-    if interpret is None:
-        interpret = default_interpret()
-    if block_size is not None and not interpret and \
-            (block_size % LANE or a.d_head % LANE):
-        return False
-    return True
+    return a is not None and a.block_regular
 
 
-def resolve_paged_kernel(plan, block_size: int, requested: str,
-                         interpret: Optional[bool] = None) -> str:
+def resolve_paged_kernel(plan, requested: str) -> str:
     """Resolve a ``paged_kernel`` request to the dataflow that will run.
 
     ``"auto"`` becomes ``"stream"`` when :func:`paged_stream_supported`
@@ -65,15 +47,14 @@ def resolve_paged_kernel(plan, block_size: int, requested: str,
     if requested not in ("auto", "stream", "gather"):
         raise ValueError(f"paged_kernel={requested!r} not in "
                          "('auto', 'stream', 'gather')")
-    ok = paged_stream_supported(plan, block_size, interpret)
+    ok = paged_stream_supported(plan)
     if requested == "auto":
         return "stream" if ok else "gather"
     if requested == "stream" and not ok:
         raise ValueError(
             "paged_kernel='stream' needs a block-regular stored GQA "
-            "layout and (compiled on TPU) LANE-aligned block_size/"
-            f"d_head; plan for {plan.arch} with block_size={block_size} "
-            "cannot stream (use 'gather' or 'auto')")
+            f"layout; plan for {plan.arch} cannot stream (use 'gather' "
+            "or 'auto')")
     return requested
 
 
@@ -112,22 +93,33 @@ def plan_block_s(S: int, dh: int, gs: int, dtype_bytes: int = 2,
 @partial(jax.jit, static_argnames=("use_pallas", "interpret", "block_s"))
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      lengths: jax.Array, *, use_pallas: bool = True,
-                     interpret: bool = True,
+                     interpret: Optional[bool] = None,
                      block_s: int = 0) -> jax.Array:
     """q: (B,H,dh); k,v: (B,S,G,dh); lengths: (B,) -> (B,H,dh).
 
-    ``block_s`` overrides the planned KV stream tile (0 = let
-    :func:`plan_block_s` choose).
+    ``use_pallas=False`` asks for the oracle by name.  ``block_s``
+    overrides the planned KV stream tile (0 = let :func:`plan_block_s`
+    choose).  Shapes the kernel cannot take raise.
     """
     B, H, dh = q.shape
     S, G = k.shape[1], k.shape[2]
-    if (not use_pallas) or H % G or S % LANE or dh % LANE:
-        # oracle fallback (expand KV to H heads)
+    if not use_pallas:
         gs = max(H // G, 1)
         ke = jnp.repeat(k, gs, axis=2)[:, :, :H]
         ve = jnp.repeat(v, gs, axis=2)[:, :, :H]
         return decode_attention_ref(q, ke, ve, lengths)
-    bs = plan_block_s(S, dh, H // G, k.dtype.itemsize, override=block_s)
+    if H % G:
+        raise ValueError(f"decode_attention: {H} q heads do not group "
+                         f"over {G} kv heads")
+    if not interpret_mode(interpret) and G > 1:
+        # the dense (B, S, G, dh) cache hands the kernel a (block_s, 1)
+        # slice of its (G, dh) trailing plane, which Mosaic refuses
+        raise ValueError("decode_attention: the compiled dense kernel "
+                         f"takes one kv head per cache (got G={G})")
+    bs = min(plan_block_s(S, dh, H // G, k.dtype.itemsize,
+                          override=block_s), S)
+    if S % bs:
+        raise ValueError(f"decode_attention: no KV tile divides S={S}")
     return decode_attention_pallas(q, k, v, lengths, block_s=bs,
                                    interpret=interpret)
 
@@ -141,53 +133,33 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            k_scale: Optional[jax.Array] = None,
                            v_scale: Optional[jax.Array] = None,
                            use_pallas: bool = True,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: Optional[bool] = None) -> jax.Array:
     """Paged decode attention over a shared block pool.
 
-    q: (B,H,dh); k_pages,v_pages: (N,bs,G,dh); block_tables: (B,T);
-    lengths: (B,) -> (B,H,dh).  The pallas path streams KV tiles straight
-    from the pool through the block-table indirection (no contiguous copy);
-    the fallback gathers the per-request view and reuses the dense oracle.
+    q: (B,H,dh); k_pages,v_pages: (N,G,bs,dh) head-major; block_tables:
+    (B,T); lengths: (B,) -> (B,H,dh).  The Pallas kernel streams KV tiles
+    straight from the pool through the block-table indirection (no
+    contiguous copy).  ``use_pallas=False`` asks for the gather oracle
+    (:func:`paged_decode_attention_ref`) by name; nothing falls back to it.
 
     ``k_new/v_new`` ((B,G,dh)): the current token's K/V, attended *in
     addition to* the ``lengths`` resident positions — the pool is read
     pre-update and the caller scatters the new row afterwards, so decode
-    never rewrites (or copies) the pool to append one token.  The LANE
-    alignment guard only applies to compiled TPU tiles; interpret mode
-    (CPU CI) streams any block size.
+    never rewrites (or copies) the pool to append one token.
 
-    ``k_scale/v_scale`` ((N,bs,G)): absmax scale side-arrays of a
-    quantized (int8/fp8) pool.  The stream path dequantizes inside the
-    kernel's tile loop; the gather-oracle path dequantizes the gathered
-    view — both read the pool at the quantized byte width.
+    ``k_scale/v_scale`` ((N,G,bs)): absmax scale side-arrays of a
+    quantized (int8/fp8) pool.  The kernel dequantizes inside its tile
+    loop; the oracle dequantizes the gathered view — both read the pool
+    at the quantized byte width.
     """
-    B, H, dh = q.shape
-    bs, G = k_pages.shape[1], k_pages.shape[2]
-    misaligned = (bs % LANE or dh % LANE) and not interpret
-    if (not use_pallas) or H % G or misaligned:
-        gs = max(H // G, 1)
-        kg = gather_kv_pages(k_pages, block_tables)
-        vg = gather_kv_pages(v_pages, block_tables)
-        if k_scale is not None:
-            kg = kg.astype(jnp.float32) * gather_kv_pages(
-                k_scale, block_tables).astype(jnp.float32)[..., None]
-            vg = vg.astype(jnp.float32) * gather_kv_pages(
-                v_scale, block_tables).astype(jnp.float32)[..., None]
-        ke = jnp.repeat(kg, gs, axis=2)[:, :, :H]
-        ve = jnp.repeat(vg, gs, axis=2)[:, :, :H]
-        if k_new is not None:
-            # oracle fold: mask-scatter the new token at its position in
-            # the gathered view, extend the valid length by one
-            kn = jnp.repeat(k_new, gs, axis=1)[:, :H]
-            vn = jnp.repeat(v_new, gs, axis=1)[:, :H]
-
-            def put(view, row, pos):
-                return jax.lax.dynamic_update_slice(
-                    view, row[None].astype(view.dtype), (pos, 0, 0))
-            ke = jax.vmap(put)(ke, kn, lengths)
-            ve = jax.vmap(put)(ve, vn, lengths)
-            return decode_attention_ref(q, ke, ve, lengths + 1)
-        return decode_attention_ref(q, ke, ve, lengths)
+    if not use_pallas:
+        return paged_decode_attention_ref(
+            q, k_pages, v_pages, block_tables, lengths, k_new=k_new,
+            v_new=v_new, k_scale=k_scale, v_scale=v_scale)
+    H, G = q.shape[1], k_pages.shape[1]
+    if H % G:
+        raise ValueError(f"paged_decode_attention: {H} q heads do not "
+                         f"group over {G} kv heads")
     return paged_decode_attention_pallas(q, k_pages, v_pages, block_tables,
                                          lengths, k_new=k_new, v_new=v_new,
                                          k_scale=k_scale, v_scale=v_scale,

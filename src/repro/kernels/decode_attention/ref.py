@@ -10,30 +10,35 @@ import jax.numpy as jnp
 def gather_kv_pages(pages: jax.Array, block_tables: jax.Array) -> jax.Array:
     """Materialize the contiguous per-request view of a paged KV pool.
 
-    pages: (N, bs, G, dh) shared block pool; block_tables: (B, T) physical
-    block id per logical block (entries past the used length point at the
+    pages: (N, G, bs, dh) head-major block pool, or a quantized pool's
+    (N, G, bs) scale side-array; block_tables: (B, T) physical block id
+    per logical block (entries past the used length point at the
     reserved null block 0 and are masked by ``lengths`` downstream).
-    Returns (B, T*bs, G, dh).
+    Returns the seq-major view (B, T*bs, G, dh) (scales: (B, T*bs, G)).
     """
     B, T = block_tables.shape
-    bs = pages.shape[1]
-    g = pages[block_tables]                       # (B, T, bs, G, dh)
-    return g.reshape(B, T * bs, *pages.shape[2:])
+    g = jnp.moveaxis(pages[block_tables], 3, 2)   # (B, T, bs, G[, dh])
+    return g.reshape(B, T * g.shape[2], *g.shape[3:])
 
 
 def paged_decode_attention_ref(q: jax.Array, k_pages: jax.Array,
                                v_pages: jax.Array, block_tables: jax.Array,
                                lengths: jax.Array, *,
+                               k_new: jax.Array | None = None,
+                               v_new: jax.Array | None = None,
                                k_scale: jax.Array | None = None,
                                v_scale: jax.Array | None = None
                                ) -> jax.Array:
     """Oracle paged decode attention: gather blocks, run the dense oracle.
 
-    q: (B,H,dh); k_pages,v_pages: (N,bs,H,dh) (head count already expanded
-    to H like ``decode_attention_ref``); block_tables: (B,T); lengths: (B,).
-    ``k_scale/v_scale`` ((N,bs,H)) dequantize a quantized pool's gathered
-    view before the dense oracle runs.
+    q: (B,H,dh); k_pages,v_pages: (N,G,bs,dh) with H = G*gs;
+    block_tables: (B,T); lengths: (B,).  ``k_scale/v_scale`` ((N,G,bs))
+    dequantize a quantized pool's gathered view.  ``k_new/v_new``
+    ((B,G,dh)) are attended at position ``lengths`` in addition to the
+    resident rows, as the kernel's fold does.
     """
+    H = q.shape[1]
+    gs = max(H // k_pages.shape[1], 1)
     k = gather_kv_pages(k_pages, block_tables)
     v = gather_kv_pages(v_pages, block_tables)
     if k_scale is not None:
@@ -41,7 +46,21 @@ def paged_decode_attention_ref(q: jax.Array, k_pages: jax.Array,
             k_scale, block_tables).astype(jnp.float32)[..., None]
         v = v.astype(jnp.float32) * gather_kv_pages(
             v_scale, block_tables).astype(jnp.float32)[..., None]
-    return decode_attention_ref(q, k, v, lengths)
+    k = jnp.repeat(k, gs, axis=2)[:, :, :H]
+    v = jnp.repeat(v, gs, axis=2)[:, :, :H]
+    if k_new is None:
+        return decode_attention_ref(q, k, v, lengths)
+    # mask-scatter the new token at its position in the gathered view,
+    # extend the valid length by one
+    kn = jnp.repeat(k_new, gs, axis=1)[:, :H]
+    vn = jnp.repeat(v_new, gs, axis=1)[:, :H]
+
+    def put(view, row, pos):
+        return jax.lax.dynamic_update_slice(
+            view, row[None].astype(view.dtype), (pos, 0, 0))
+    k = jax.vmap(put)(k, kn, lengths)
+    v = jax.vmap(put)(v, vn, lengths)
+    return decode_attention_ref(q, k, v, lengths + 1)
 
 
 def decode_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,

@@ -18,11 +18,14 @@ stream saturates HBM while fitting VMEM — the LPU's
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 
 def _gemv_kernel(x_ref, w_ref, b_ref, s_ref, o_ref, acc_ref, *,
@@ -55,7 +58,7 @@ def _gemv_kernel(x_ref, w_ref, b_ref, s_ref, o_ref, acc_ref, *,
 def gemv_pallas(x: jax.Array, w: jax.Array, b: jax.Array | None = None, *,
                 w_scale: jax.Array | None = None,
                 block_n: int = 512, block_k: int = 512,
-                interpret: bool = True) -> jax.Array:
+                interpret: Optional[bool] = None) -> jax.Array:
     """x: (B, K); w: (K, N); optional b: (N,) -> (B, N).
 
     B (decode batch per device) stays whole — it is tiny by design.
@@ -92,5 +95,6 @@ def gemv_pallas(x: jax.Array, w: jax.Array, b: jax.Array | None = None, *,
         out_specs=pl.BlockSpec((B, block_n), lambda n, k: (0, n)),
         out_shape=jax.ShapeDtypeStruct((B, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((B, block_n), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
+        name="gemv",
     )(x, w, b2, s2)

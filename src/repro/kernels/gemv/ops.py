@@ -86,7 +86,7 @@ def quantize_weight(w: jax.Array,
 @partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def gemv(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None, *,
          w_scale: Optional[jax.Array] = None, use_pallas: bool = True,
-         interpret: bool = True) -> jax.Array:
+         interpret: Optional[bool] = None) -> jax.Array:
     """Decode GEMV: (B,K) x (K,N) -> (B,N), f32 accumulation.
 
     ``w_scale`` (N,) marks ``w`` as int8-quantized per output column;
@@ -96,9 +96,11 @@ def gemv(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None, *,
         return gemv_ref(x, w, b, w_scale=w_scale)
     B, K = x.shape
     N = w.shape[1]
-    if K % LANE or N % LANE:
-        return gemv_ref(x, w, b, w_scale=w_scale)  # unaligned: oracle path
     bk, bn = plan_blocks(B, K, N, w_bytes=w.dtype.itemsize,
                          x_bytes=x.dtype.itemsize)
+    # a dim that no LANE-aligned tile divides is taken whole: Mosaic
+    # accepts a block dim that spans its array
+    bk = K if K % LANE else bk
+    bn = N if N % LANE else bn
     return gemv_pallas(x, w, b, w_scale=w_scale, block_k=bk, block_n=bn,
                        interpret=interpret)

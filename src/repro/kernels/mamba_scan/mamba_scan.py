@@ -16,12 +16,14 @@ decode path.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 
 def _scan_kernel(da_ref, bx_ref, c_ref, h0_ref, y_ref, hout_ref, h_ref,
@@ -51,7 +53,8 @@ def _scan_kernel(da_ref, bx_ref, c_ref, h0_ref, y_ref, hout_ref, h_ref,
 
 def mamba_scan_pallas(da: jax.Array, bx: jax.Array, c: jax.Array,
                       h0: jax.Array, *, block_s: int = 128,
-                      block_c: int = 128, interpret: bool = True
+                      block_c: int = 128,
+                      interpret: Optional[bool] = None
                       ) -> Tuple[jax.Array, jax.Array]:
     """da, bx: (B,S,C,N); c: (B,S,N); h0: (B,C,N) -> (y (B,S,C), h (B,C,N))."""
     B, S, C, N = da.shape
@@ -83,6 +86,7 @@ def mamba_scan_pallas(da: jax.Array, bx: jax.Array, c: jax.Array,
             jax.ShapeDtypeStruct((B, C, N), h0.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((block_c, N), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
+        name="mamba_scan",
     )(da, bx, c, h0)
     return y, h_fin

@@ -2,7 +2,7 @@
 from __future__ import annotations
 
 from functools import partial
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 
@@ -12,11 +12,15 @@ from repro.kernels.mamba_scan.ref import mamba_scan_ref
 
 @partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def mamba_scan(da: jax.Array, bx: jax.Array, c: jax.Array, h0: jax.Array,
-               *, use_pallas: bool = True, interpret: bool = True
+               *, use_pallas: bool = True,
+               interpret: Optional[bool] = None
                ) -> Tuple[jax.Array, jax.Array]:
     B, S, C, N = da.shape
-    if not use_pallas or S % 8 or C % 8:
+    if not use_pallas:
         return mamba_scan_ref(da, bx, c, h0)
+    if S % 8 or C % 8:
+        raise ValueError(f"mamba_scan: S={S} and C={C} must be multiples "
+                         "of 8 (use_pallas=False runs the oracle)")
     bs = 128
     while S % bs:
         bs //= 2

@@ -11,12 +11,14 @@ the state never touches HBM until the final flush.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
@@ -49,7 +51,8 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
 
 def rwkv_scan_pallas(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
                      u: jax.Array, s0: jax.Array, *, block_s: int = 128,
-                     interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+                     interpret: Optional[bool] = None
+                     ) -> Tuple[jax.Array, jax.Array]:
     """r,k,v,w: (B,S,H,dh) f32; u: (H,dh); s0: (B,H,dh,dh)."""
     B, S, H, dh = r.shape
     block_s = min(block_s, S)
@@ -78,6 +81,7 @@ def rwkv_scan_pallas(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
             jax.ShapeDtypeStruct((B, H, dh, dh), s0.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
+        name="rwkv_scan",
     )(r, k, v, w, u, s0)
     return y, s_fin

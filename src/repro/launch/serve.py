@@ -39,6 +39,10 @@ accepts a prefix — greedy streams bit-identical, stochastic streams
 exactly target-distributed; ``model`` drafts with a reduced smollm-135m
 running greedily at batch 1).
 
+Plans take the mapper's serve dtypes: bf16 weights and compute.  The
+persistent compilation cache lives where ``JAX_COMPILATION_CACHE_DIR``
+says, else in ``<repo>/.jax_cache``.
+
 Precision knobs: ``--kv-dtype {auto,float16,bfloat16,float32,int8,fp8}``
 (KV pool storage precision — int8/fp8 store per-(row, head) absmax
 scales beside the pool and dequantize inside the paged kernel's tile
@@ -72,6 +76,7 @@ import numpy as np  # noqa: E402
 from repro.compiler.mapper import plan_model  # noqa: E402
 from repro.configs import get_config  # noqa: E402
 from repro.core.latency_model import LPU_FPGA, step_time_prior  # noqa: E402
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.launch.mesh import make_serving_mesh  # noqa: E402
 from repro.models.registry import build_model  # noqa: E402
 from repro.serving.config import EngineConfig  # noqa: E402
@@ -253,6 +258,7 @@ def main():
                          "jsonl file (forces the async frontend path)")
     args = ap.parse_args()
 
+    print(f"[serve] compile cache: {use_compile_cache()}")
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -260,13 +266,11 @@ def main():
     if tp > 1 or rings > 1:
         mesh = make_serving_mesh(tp=tp, rings=rings)
         plan = plan_model(cfg, ("model",), (tp,), "serve",
-                          esl_overlap=not args.no_overlap, remat="none",
-                          compute_dtype="float32", param_dtype="float32")
+                          esl_overlap=not args.no_overlap, remat="none")
     else:
         mesh = None
         plan = plan_model(cfg, None, (1,), "serve", esl_overlap=False,
-                          remat="none", compute_dtype="float32",
-                          param_dtype="float32")
+                          remat="none")
     model = build_model(cfg, plan)
     params, _ = model.init(jax.random.PRNGKey(0))
     draft_model = draft_params = None
@@ -276,8 +280,7 @@ def main():
         # to the target
         dcfg = get_config("smollm-135m").reduced()
         dplan = plan_model(dcfg, None, (1,), "serve", esl_overlap=False,
-                           remat="none", compute_dtype="float32",
-                           param_dtype="float32")
+                           remat="none")
         draft_model = build_model(dcfg, dplan)
         draft_params, _ = draft_model.init(jax.random.PRNGKey(1))
     econf = EngineConfig(slots=args.slots, max_seq=args.max_seq,

@@ -29,10 +29,10 @@ from jax import lax
 
 from repro.core import esl
 from repro.core.dist import AxisEnv, model_rank
-from repro.kernels.decode_attention import ops as da_ops
 from repro.kernels.decode_attention.ops import (paged_decode_attention,
                                                 paged_stream_supported,
                                                 resolve_paged_kernel)
+from repro.kernels.decode_attention.ref import gather_kv_pages
 from repro.models.common import InitCtx, apply_rope, big_neg
 
 Params = Dict[str, Any]
@@ -287,7 +287,7 @@ def chunk_prefill_attention(p: Params, x: jax.Array, *, cfg, plan,
     x:            (1, C, D[/tp]) the chunk activations (C is static —
                   ONE trace total, vs O(log2 max_seq) pow2 buckets)
     positions:    (1, C) absolute positions ``start + [0..C)``
-    cache:        {'k','v': (N, bs, kpr, dh)} the shared block pool
+    cache:        {'k','v': (N, kpr, bs, dh)} the shared block pool
                   (rank-local head shard under ring tp, like decode)
     block_table:  (1, T) this request's physical block ids
     kv_valid_len: scalar — total resident tokens AFTER this chunk
@@ -342,25 +342,15 @@ def chunk_prefill_attention(p: Params, x: jax.Array, *, cfg, plan,
         new_cache["k_scale"], new_cache["v_scale"] = ks, vs
 
     C = q.shape[1]
-    bs = kc.shape[1]
-    mode = resolve_paged_kernel(plan, bs, paged_kernel)
+    mode = resolve_paged_kernel(plan, paged_kernel)
     if mode == "stream":
         # per-query causal span: history + self (clamped for pad rows)
         lens = jnp.minimum(pos + 1, kv_valid_len)
         tabs = jnp.broadcast_to(table[None], (C, table.shape[0]))
         out = paged_decode_attention(
-            q[0], kc, vc, tabs, lens, k_scale=ks, v_scale=vs,
-            use_pallas=True,
-            interpret=da_ops.default_interpret())[None]
+            q[0], kc, vc, tabs, lens, k_scale=ks, v_scale=vs)[None]
     else:
-        T = table.shape[0]
-        kview = kc[table].reshape(1, T * bs, kc.shape[2], kc.shape[3])
-        vview = vc[table].reshape(1, T * bs, vc.shape[2], vc.shape[3])
-        if quantized:
-            kview = kview.astype(jnp.float32) * \
-                ks[table].reshape(1, T * bs, ks.shape[2])[..., None]
-            vview = vview.astype(jnp.float32) * \
-                vs[table].reshape(1, T * bs, vs.shape[2])[..., None]
+        kview, vview = _gather_pool_view(kc, vc, ks, vs, table[None])
         kmap = local_kmap(plan, env)
         ke = _expand_kv(kview, kmap, a.q_per_rank)
         ve = _expand_kv(vview, kmap, a.q_per_rank)
@@ -430,24 +420,12 @@ def verify_attention(p: Params, x: jax.Array, *, cfg, plan,
     if quantized:
         new_cache["k_scale"], new_cache["v_scale"] = ks, vs
 
-    bs = kc.shape[1]
-    mode = resolve_paged_kernel(plan, bs, paged_kernel)
+    mode = resolve_paged_kernel(plan, paged_kernel)
     if mode == "stream":
         out = paged_decode_attention(
-            q[0], kc, vc, block_tables, lens, k_scale=ks, v_scale=vs,
-            use_pallas=True,
-            interpret=da_ops.default_interpret())[None]
+            q[0], kc, vc, block_tables, lens, k_scale=ks, v_scale=vs)[None]
     else:
-        Q, T = block_tables.shape
-        kview = kc[block_tables].reshape(Q, T * bs, kc.shape[2],
-                                         kc.shape[3])
-        vview = vc[block_tables].reshape(Q, T * bs, vc.shape[2],
-                                         vc.shape[3])
-        if quantized:
-            kview = kview.astype(jnp.float32) * \
-                ks[block_tables].reshape(Q, T * bs, ks.shape[2])[..., None]
-            vview = vview.astype(jnp.float32) * \
-                vs[block_tables].reshape(Q, T * bs, vs.shape[2])[..., None]
+        kview, vview = _gather_pool_view(kc, vc, ks, vs, block_tables)
         kmap = local_kmap(plan, env)
         ke = _expand_kv(kview, kmap, a.q_per_rank)
         ve = _expand_kv(vview, kmap, a.q_per_rank)
@@ -476,7 +454,7 @@ def decode_attention(p: Params, x: jax.Array, *, cfg, plan, env: AxisEnv,
     activation vector against streamed weights + streamed KV.
 
     Paged mode (``block_table`` given): cache['k'/'v'] is the shared block
-    pool (N, bs, kpr, dh).  ``paged_kernel`` selects the dataflow:
+    pool (N, kpr, bs, dh).  ``paged_kernel`` selects the dataflow:
 
     * ``"stream"`` — the Pallas paged kernel streams KV tiles straight
       from the pool via the scalar-prefetched block table; the new
@@ -508,7 +486,7 @@ def decode_attention(p: Params, x: jax.Array, *, cfg, plan, env: AxisEnv,
     if block_table is not None:
         assert env.kv_seq_axis is None, \
             "paged KV shards heads over the model ring, not the seq axis"
-        mode = resolve_paged_kernel(plan, kc.shape[1], paged_kernel)
+        mode = resolve_paged_kernel(plan, paged_kernel)
         quantized = "k_scale" in cache
         if quantized:
             from repro.serving.kv_cache import (dequantize_kv,
@@ -536,20 +514,11 @@ def decode_attention(p: Params, x: jax.Array, *, cfg, plan, env: AxisEnv,
             out = paged_decode_attention(
                 q[:, 0], kc, vc, block_table, positions,
                 k_new=k_fold[:, 0], v_new=v_fold[:, 0],
-                k_scale=cache.get("k_scale"), v_scale=cache.get("v_scale"),
-                use_pallas=True,
-                interpret=da_ops.default_interpret())[:, None]
+                k_scale=cache.get("k_scale"),
+                v_scale=cache.get("v_scale"))[:, None]
             return out_proj(p, out, env, plan), updates
-        B, T = block_table.shape
-        bs = kc.shape[1]
-        kc = kc[block_table].reshape(B, T * bs, kc.shape[2], kc.shape[3])
-        vc = vc[block_table].reshape(B, T * bs, vc.shape[2], vc.shape[3])
-        if quantized:
-            G = cache["k_scale"].shape[2]
-            kc = kc.astype(jnp.float32) * cache["k_scale"][
-                block_table].reshape(B, T * bs, G)[..., None]
-            vc = vc.astype(jnp.float32) * cache["v_scale"][
-                block_table].reshape(B, T * bs, G)[..., None]
+        kc, vc = _gather_pool_view(kc, vc, cache.get("k_scale"),
+                                   cache.get("v_scale"), block_table)
         kmap = local_kmap(plan, env)
         out = _flash_decode_chunked(q, kc, vc, kmap,
                                     kv_valid_len=positions,
@@ -707,6 +676,22 @@ def _flash_decode_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
 # cache plumbing
 # ---------------------------------------------------------------------------
 
+def _gather_pool_view(kc: jax.Array, vc: jax.Array,
+                      ks: Optional[jax.Array], vs: Optional[jax.Array],
+                      tables: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The gather oracle's contiguous (B, T*bs, kpr, dh) K/V view of the
+    head-major pool through ``tables`` (B, T), dequantized when the pool
+    carries scale side-arrays ``ks``/``vs``."""
+    kview = gather_kv_pages(kc, tables)
+    vview = gather_kv_pages(vc, tables)
+    if ks is not None:
+        kview = kview.astype(jnp.float32) * \
+            gather_kv_pages(ks, tables).astype(jnp.float32)[..., None]
+        vview = vview.astype(jnp.float32) * \
+            gather_kv_pages(vs, tables).astype(jnp.float32)[..., None]
+    return kview, vview
+
+
 def _cache_insert_prefix(cache: jax.Array, kv: jax.Array,
                          env: AxisEnv) -> jax.Array:
     """Write the prefill K/V into cache[:, :S]."""
@@ -795,14 +780,18 @@ def init_cache(plan, batch: int, max_seq: int, dtype=jnp.bfloat16,
     sharding the stored seq dim is max_seq/width per rank (global held
     as rank-major).
 
-    Paged (``paged=True``): a shared pool (num_blocks, block_size, Gp,
+    Paged (``paged=True``): a shared pool (num_blocks, Gp, block_size,
     dh) with **no batch dimension** — requests own disjoint block sets
     via block tables (block 0 reserved as the null block).  Memory
-    scales with resident tokens, not slots x worst-case length.
+    scales with resident tokens, not slots x worst-case length.  The
+    pool is head-major: each (block, head) is one contiguous
+    (block_size, dh) plane, the tile the paged kernel streams (a
+    seq-major (block_size, Gp, dh) block would hand Mosaic a (1, dh)
+    slice of a (Gp, dh) plane, which it refuses for Gp > 1).
 
     ``scale_dtype`` (paged only) marks the pool as quantized: ``dtype``
     is the int8/fp8 storage type and two scale side-arrays
-    ``k_scale``/``v_scale`` of shape (num_blocks, block_size, Gp) ride
+    ``k_scale``/``v_scale`` of shape (num_blocks, Gp, block_size) ride
     alongside the values — one absmax scale per stored row per head,
     zero-initialized so the null block dequantizes to exact zeros.
     """
@@ -811,7 +800,7 @@ def init_cache(plan, batch: int, max_seq: int, dtype=jnp.bfloat16,
     if paged:
         assert kv_seq_width == 1, "paged cache is single-rank (no kv-seq)"
         assert num_blocks >= 2 and block_size > 0, (num_blocks, block_size)
-        shape = (num_blocks, block_size, gp, a.d_head)
+        shape = (num_blocks, gp, block_size, a.d_head)
     else:
         assert scale_dtype is None, \
             "quantized KV storage needs the paged pool (row scatters " \

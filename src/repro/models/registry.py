@@ -131,7 +131,7 @@ class Model:
         """PartitionSpec tree matching init_cache (decoder-only families).
 
         ``paged=True`` describes the shared block pool: stacked per-layer
-        leaves are (n_sb, num_blocks, block_size, Gp, dh) with the stored
+        leaves are (n_sb, num_blocks, Gp, block_size, dh) with the stored
         kv heads (Gp) sharded over the model ring — each rank holds its
         head shard of EVERY block, so one host-side block table drives
         all ranks and pool bytes split 1/tp per rank.  ``kv_quant=True``
@@ -157,11 +157,11 @@ class Model:
                                 "wkv": P(None, dp, m, None, None)}
             elif cfg.is_attention_layer(j):
                 if paged:
-                    out[f"l{j}"] = {"k": P(None, None, None, m, None),
-                                    "v": P(None, None, None, m, None)}
+                    out[f"l{j}"] = {"k": P(None, None, m, None, None),
+                                    "v": P(None, None, m, None, None)}
                     if kv_quant:
-                        out[f"l{j}"]["k_scale"] = P(None, None, None, m)
-                        out[f"l{j}"]["v_scale"] = P(None, None, None, m)
+                        out[f"l{j}"]["k_scale"] = P(None, None, m, None)
+                        out[f"l{j}"]["v_scale"] = P(None, None, m, None)
                 elif kv_w > 1:
                     out[f"l{j}"] = {"k": P(None, dp, env.kv_seq_axis, None,
                                            m, None),

@@ -347,27 +347,30 @@ def _scatter_cache_updates(cache_st, upd, idx, seq_sharded: bool,
             pos, mask = u["pos"], u["mask"]
             b_idx = jnp.arange(knew.shape[0])
             if block_tables is not None:
-                # paged pool (n_sb, N, bs, gp, dh): the token at logical
-                # position ``pos`` lands in physical block
-                # table[b, pos // bs] at offset pos % bs.  Inactive slots
-                # point at the null block 0 (don't-care writes).
-                bs_blk = c["k"].shape[2]
+                # head-major pool (n_sb, N, gp, bs, dh): the token at
+                # logical position ``pos`` lands in physical block
+                # table[b, pos // bs] at offset pos % bs of every head.
+                # Inactive slots point at the null block 0 (don't-care
+                # writes).
+                bs_blk = c["k"].shape[3]
                 blk = jnp.take_along_axis(
                     block_tables, (pos // bs_blk)[:, None], axis=1)[:, 0]
                 off = pos % bs_blk
                 out[lj] = {
-                    "k": c["k"].at[idx, blk, off].set(
+                    "k": c["k"].at[idx, blk, :, off].set(
                         knew[:, 0].astype(c["k"].dtype)),
-                    "v": c["v"].at[idx, blk, off].set(
+                    "v": c["v"].at[idx, blk, :, off].set(
                         vnew[:, 0].astype(c["v"].dtype)),
                 }
                 if "k_scale_new" in u:
                     # quantized pool: the row's absmax scales land beside
                     # the int8/fp8 values at the same (block, offset)
-                    out[lj]["k_scale"] = c["k_scale"].at[idx, blk, off].set(
-                        u["k_scale_new"][:, 0].astype(c["k_scale"].dtype))
-                    out[lj]["v_scale"] = c["v_scale"].at[idx, blk, off].set(
-                        u["v_scale_new"][:, 0].astype(c["v_scale"].dtype))
+                    out[lj]["k_scale"] = c["k_scale"].at[
+                        idx, blk, :, off].set(u["k_scale_new"][:, 0].astype(
+                            c["k_scale"].dtype))
+                    out[lj]["v_scale"] = c["v_scale"].at[
+                        idx, blk, :, off].set(u["v_scale_new"][:, 0].astype(
+                            c["v_scale"].dtype))
             elif seq_sharded and c["k"].ndim == 6:
                 old_k = c["k"][idx, b_idx, 0, pos]
                 old_v = c["v"][idx, b_idx, 0, pos]

@@ -92,6 +92,7 @@ from repro.compiler.plan import resolve_kv_precision
 from repro.core.compat import shard_map
 from repro.core.dist import make_axis_env
 from repro.core.rings import reconfigure, submeshes
+from repro.kernels import interpret_mode
 from repro.kernels.decode_attention.ops import (plan_block_s,
                                                 resolve_paged_kernel)
 from repro.serving.kv_cache import (LANE, BlockPool, PrefixCache,
@@ -303,11 +304,6 @@ class LPUEngine:
                     f"kv_dtype={c.kv_dtype!r} needs the paged KV pool: "
                     "quantization is a pool-storage contract (scales "
                     "live beside pool blocks); dense caches store fp")
-            if self.kv_prec.store_dtype == "float8_e4m3fn" and \
-                    not hasattr(jnp, "float8_e4m3fn"):
-                raise ValueError(
-                    "kv_dtype='fp8' needs jnp.float8_e4m3fn, which this "
-                    "jax build does not provide; use kv_dtype='int8'")
         self.kv_dtype = self.kv_prec.store_dtype
         # w_dtype is the streamed-weight precision of the gemv decode
         # chain (core/streamline.decode_layer + kernels/gemv); the
@@ -372,13 +368,17 @@ class LPUEngine:
         # straight off the pool (scalar-prefetched block table, no
         # contiguous per-request copy); "gather" keeps the materialized
         # (B, T*bs) view as the reference oracle; "auto" streams
-        # whenever the stored GQA layout (and, compiled on TPU, the
-        # tile alignment) allows it.  Resolved AFTER block_size so the
-        # choice — and the kv_bytes_moved accounting keyed off it — is
-        # what the decode program will actually execute.
-        self.paged_kernel = (resolve_paged_kernel(
-            self.plan, self.block_size, paged_kernel) if self.paged
-            else None)
+        # whenever the stored GQA layout allows it.  The choice — and the
+        # kv_bytes_moved accounting keyed off it — is what the decode
+        # program will actually execute.
+        self.paged_kernel = (resolve_paged_kernel(self.plan, paged_kernel)
+                             if self.paged else None)
+        if self.paged_kernel == "stream" and not interpret_mode() and \
+                self.kv_dtype == "float16":
+            raise ValueError(
+                "kv_dtype='float16' cannot stream on this TPU: Mosaic has "
+                "no fp16 vector loads for the paged kernel's pool tiles; "
+                "use kv_dtype='bfloat16'")
         if self.block_s and self.paged_kernel == "stream" and \
                 self.block_s != self.block_size:
             raise ValueError(
@@ -2003,9 +2003,10 @@ class MultiRingEngine:
         check at the end of the round eventually drains it."""
         self._round += 1
         done: List[Request] = []
+        alive: List[int] = []
         for i, eng in enumerate(self.engines):
             if not eng.sched.has_work():
-                self.hb.beat(i)
+                alive.append(i)
                 continue
             before = eng.stats.steps + eng.stats.prefills
             t0 = time.perf_counter()
@@ -2027,7 +2028,13 @@ class MultiRingEngine:
             progressed = (eng.stats.steps + eng.stats.prefills) > before \
                 or not eng.sched.has_work()
             if progressed:
-                self.hb.beat(i)
+                alive.append(i)
+        # beat at the end of the round: one host thread steps the rings
+        # in turn, so the time another ring's step takes (a cold compile
+        # takes a minute on the chip) is not silence of this one
+        now = self._clock()
+        for i in alive:
+            self.hb.beat(i, now)
         if isinstance(self._clock, ManualClock):
             self._clock.advance(self.round_dt)
         for i in self.hb.check():

@@ -392,12 +392,14 @@ def quantize_kv_rows(rows: jax.Array, store_dtype,
     ``rows.shape[:-1]`` in ``scale_dtype`` — one scale per stored token
     row per kv head, the side array the pool carries next to the values.
     All-zero rows get scale 0 (dequantizes to exact zeros, the null
-    block's contract); the divisor is made safe so they never NaN.
+    block's contract); the divisor is made safe so they never NaN.  The
+    values are divided by the scale as stored (rounded to
+    ``scale_dtype``), so dequantization inverts exactly what was stored.
     """
     qmax = qmax_for_dtype(store_dtype)
     x = rows.astype(jnp.float32)
     amax = jnp.max(jnp.abs(x), axis=-1)
-    scale = amax / qmax
+    scale = (amax / qmax).astype(scale_dtype).astype(jnp.float32)
     y = x / jnp.where(scale > 0, scale, 1.0)[..., None]
     if jnp.dtype(store_dtype) == jnp.int8:
         q = jnp.clip(jnp.round(y), -qmax, qmax).astype(store_dtype)
@@ -427,14 +429,14 @@ def scatter_prefill_pages(cache: Params, prefill_cache: Params,
                           table: jax.Array) -> Params:
     """Copy a batch=1 prefill cache into the shared block pool.
 
-    cache:         {lj: {"k": (n_sb, N, bs, gp, dh), "v": ...}}
+    cache:         {lj: {"k": (n_sb, N, gp, bs, dh), "v": ...}}
     prefill_cache: {lj: {"k": (n_sb, 1, S, gp, dh), "v": ...}} with S a
                    multiple of bs
     table:         (S // bs,) physical block ids; pad entries point at the
                    null block 0, which absorbs the padded tokens' KV.
 
     A quantized pool carries ``k_scale``/``v_scale`` side-array leaves
-    ((n_sb, N, bs, gp)); prefill stays full-precision in its bucket
+    ((n_sb, N, gp, bs)); prefill stays full-precision in its bucket
     cache and quantization happens HERE, at pool-write time, so the
     quantized path shares one numerical contract with the chunked /
     speculative row scatters.
@@ -445,10 +447,12 @@ def scatter_prefill_pages(cache: Params, prefill_cache: Params,
         layer: Params = {}
         for key in ("k", "v"):
             pg, dn = c[key], pc[key]
-            n_sb, _, bs = pg.shape[0], pg.shape[1], pg.shape[2]
+            n_sb, bs = pg.shape[0], pg.shape[3]
             S = dn.shape[2]
             nb = S // bs
-            chunks = dn[:, 0].reshape((n_sb, nb, bs) + dn.shape[3:])
+            # seq-major (S, gp, dh) rows -> head-major (nb, gp, bs, dh)
+            chunks = jnp.swapaxes(
+                dn[:, 0].reshape((n_sb, nb, bs) + dn.shape[3:]), 2, 3)
             skey = key + "_scale"
             if skey in c:
                 spg = c[skey]
@@ -474,7 +478,7 @@ def scatter_chunk_rows(pages: jax.Array, rows: jax.Array,
     through the request's table, exactly like the decode path's
     one-token scatter.
 
-    pages:       (N, bs, G, dh) one layer of the shared pool
+    pages:       (N, G, bs, dh) one layer of the head-major pool
     rows:        (C, G, dh) the chunk's freshly computed K (or V)
     block_table: (T,) the request's physical block ids
     positions:   (C,) absolute token positions of the chunk rows
@@ -482,16 +486,16 @@ def scatter_chunk_rows(pages: jax.Array, rows: jax.Array,
                  (absorbed don't-care traffic, masked on read).
 
     Shapes generalize over the trailing dims: a quantized pool's scale
-    side-array ((N, bs, G) pages, (C, G) rows) scatters through the
+    side-array ((N, G, bs) pages, (C, G) rows) scatters through the
     SAME function, so values and scales stay row-consistent by
     construction.
     """
-    bs = pages.shape[1]
+    bs = pages.shape[2]
     T = block_table.shape[0]
     idx = jnp.clip(positions // bs, 0, T - 1)
     blk = jnp.where(valid, block_table[idx], 0)
     off = positions % bs
-    return pages.at[blk, off].set(rows.astype(pages.dtype))
+    return pages.at[blk, :, off].set(rows.astype(pages.dtype))
 
 
 def scatter_spec_rows(pages: jax.Array, rows: jax.Array,
@@ -507,20 +511,21 @@ def scatter_spec_rows(pages: jax.Array, rows: jax.Array,
     later read masks them out and the next window overwrites them
     idempotently (logical rollback, zero device work).
 
-    pages:        (N, bs, G, dh) one layer of the shared pool
+    pages:        (N, G, bs, dh) one layer of the head-major pool
+                  (or its (N, G, bs) scale side-array)
     rows:         (Q, G, dh) the verify queries' freshly computed K (V)
     block_tables: (Q, T) each query's physical block ids
     positions:    (Q,) absolute token positions
     valid:        (Q,) bool; idle-slot rows route to the null block 0.
     """
-    bs = pages.shape[1]
+    bs = pages.shape[2]
     T = block_tables.shape[1]
     idx = jnp.clip(positions // bs, 0, T - 1)
     blk = jnp.where(valid,
                     jnp.take_along_axis(block_tables, idx[:, None],
                                         1)[:, 0], 0)
     off = positions % bs
-    return pages.at[blk, off].set(rows.astype(pages.dtype))
+    return pages.at[blk, :, off].set(rows.astype(pages.dtype))
 
 
 def scatter_prefill_dense(cache: Params, prefill_cache: Params,

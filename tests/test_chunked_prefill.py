@@ -235,8 +235,8 @@ def test_streamline_chunk_layer_matches_sequential_decode():
     S, C = 13, 8                      # 2 chunks; second is padded
     xs = jax.random.normal(jax.random.PRNGKey(1), (S, cfg.d_model))
 
-    pool = {"k": jnp.zeros((T + 1, bs, a.gp, a.d_head)),
-            "v": jnp.zeros((T + 1, bs, a.gp, a.d_head))}
+    pool = {"k": jnp.zeros((T + 1, a.gp, bs, a.d_head)),
+            "v": jnp.zeros((T + 1, a.gp, bs, a.d_head))}
     ys, cache = [], pool
     for i in range(S):
         y, cache = decode_layer(p, xs[i:i + 1], cache,
@@ -257,10 +257,14 @@ def test_streamline_chunk_layer_matches_sequential_decode():
     y_chunk = np.concatenate([np.asarray(y1), np.asarray(y2)[:S - C]])
     np.testing.assert_allclose(np.stack(ys), y_chunk, rtol=1e-5,
                                atol=1e-5)
-    # the resident KV itself is identical (padded rows hit null block 0)
+    # the resident KV itself agrees (padded rows hit null block 0) to a
+    # few ULP: the chunk's K/V rows come out of an 8-row projection, the
+    # sequential ones out of 1-row projections, which XLA fuses apart
     for key in ("k", "v"):
-        np.testing.assert_array_equal(np.asarray(cache[key][1:]),
-                                      np.asarray(cache_ch[key][1:]))
+        ref = np.asarray(cache[key][1:])
+        np.testing.assert_allclose(
+            np.asarray(cache_ch[key][1:]), ref, rtol=0,
+            atol=4 * np.spacing(np.abs(ref).max()))
 
 
 def test_streamline_chunk_layer_kernel_parity():
@@ -280,8 +284,8 @@ def test_streamline_chunk_layer_kernel_parity():
     bs, T, C = 8, 4, 8
     table = jnp.arange(1, T + 1, dtype=jnp.int32)
     xs = jax.random.normal(jax.random.PRNGKey(1), (C, cfg.d_model))
-    pool = {"k": jnp.zeros((T + 1, bs, a.gp, a.d_head)),
-            "v": jnp.zeros((T + 1, bs, a.gp, a.d_head))}
+    pool = {"k": jnp.zeros((T + 1, a.gp, bs, a.d_head)),
+            "v": jnp.zeros((T + 1, a.gp, bs, a.d_head))}
     y_k, c_k = chunk_prefill_layer(p, xs, pool, table, jnp.int32(0),
                                    jnp.int32(C), cfg=cfg, plan=plan,
                                    use_kernels=True, interpret=True)

@@ -233,6 +233,30 @@ def test_fleet_retry_exhaustion_is_structured(tiny_model):
         eng.check_pool_balanced()
 
 
+def test_fleet_slow_ring_step_does_not_time_out_the_others(tiny_model):
+    # one host thread steps the rings in turn: a ring whose step takes
+    # longer than the heartbeat timeout (a cold compile) must not get
+    # the rings stepped before it drained, which would migrate their
+    # requests and recompute their streams
+    prompts = [[1, 2, 3], [4, 5], [6, 7, 8, 9], [10, 11]]
+    base = _fleet(tiny_model).generate(prompts, max_new_tokens=6)
+    model, params = tiny_model
+    clk = ManualClock()
+    fleet = MultiRingEngine(
+        model, params, None, rings=2, clock=clk,
+        config=EngineConfig(slots=2, max_seq=64, paged=True, block_size=16,
+                            heartbeat_timeout_s=4.0))
+    slow = fleet.engines[1]
+    step = slow.step
+
+    def compiling_step():
+        clk.advance(10.0)
+        return step()
+    slow.step = compiling_step
+    assert fleet.generate(prompts, max_new_tokens=6) == base
+    assert not [e for e in fleet.events if e.kind == "ring_failed"]
+
+
 def test_fleet_idle_ring_heartbeats_while_other_stalls(tiny_model):
     # ONE request: ring 0 serves it and wedges; ring 1 stays idle the
     # whole run.  Idle rings beat for free — only the stalled ring may

@@ -96,8 +96,8 @@ def test_blocks_for():
 def _paged_inputs(key, B=2, H=4, G=2, dh=128, bs=128, T=4, N=9):
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (B, H, dh), jnp.float32)
-    k_pages = jax.random.normal(ks[1], (N, bs, G, dh), jnp.float32)
-    v_pages = jax.random.normal(ks[2], (N, bs, G, dh), jnp.float32)
+    k_pages = jax.random.normal(ks[1], (N, G, bs, dh), jnp.float32)
+    v_pages = jax.random.normal(ks[2], (N, G, bs, dh), jnp.float32)
     tables = jnp.asarray([[1, 3, 5, 0], [2, 4, 6, 8]], jnp.int32)
     lengths = jnp.asarray([3 * bs - 5, 4 * bs - 61], jnp.int32)
     return q, k_pages, v_pages, tables, lengths
@@ -106,7 +106,7 @@ def _paged_inputs(key, B=2, H=4, G=2, dh=128, bs=128, T=4, N=9):
 def test_paged_kernel_bit_compatible_with_dense():
     """Same tile size => identical accumulation order => bitwise equal."""
     q, kp, vp, tables, lengths = _paged_inputs(jax.random.PRNGKey(0))
-    bs = kp.shape[1]
+    bs = kp.shape[2]
     kd = gather_kv_pages(kp, tables)
     vd = gather_kv_pages(vp, tables)
     dense = decode_attention_pallas(q, kd, vd, lengths, block_s=bs)
@@ -136,10 +136,10 @@ def test_paged_ref_fallback_matches_pallas():
 def test_paged_ref_gqa_expansion():
     """Oracle on H-expanded pages equals grouped pallas path."""
     q, kp, vp, tables, lengths = _paged_inputs(jax.random.PRNGKey(3))
-    H, G = q.shape[1], kp.shape[2]
+    H, G = q.shape[1], kp.shape[1]
     gs = H // G
-    ke = jnp.repeat(kp, gs, axis=2)
-    ve = jnp.repeat(vp, gs, axis=2)
+    ke = jnp.repeat(kp, gs, axis=1)
+    ve = jnp.repeat(vp, gs, axis=1)
     ref = paged_decode_attention_ref(q, ke, ve, tables, lengths)
     pal = paged_decode_attention(q, kp, vp, tables, lengths)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(pal),
@@ -149,7 +149,7 @@ def test_paged_ref_gqa_expansion():
 def test_null_block_never_contributes():
     """Table entries past the valid length (null block 0) are masked."""
     q, kp, vp, tables, lengths = _paged_inputs(jax.random.PRNGKey(4))
-    bs = kp.shape[1]
+    bs = kp.shape[2]
     lengths = jnp.asarray([2 * bs, 3 * bs], jnp.int32)   # 2/3 blocks valid
     out1 = paged_decode_attention(q, kp, vp, tables, lengths)
     # scribble over the null block AND the unused tail blocks

@@ -29,8 +29,8 @@ from repro.serving.engine import LPUEngine
 def _fold_inputs(key, B=2, H=4, G=2, dh=16, bs=8, T=4, N=9):
     ks = jax.random.split(key, 5)
     q = jax.random.normal(ks[0], (B, H, dh), jnp.float32)
-    kp = jax.random.normal(ks[1], (N, bs, G, dh), jnp.float32)
-    vp = jax.random.normal(ks[2], (N, bs, G, dh), jnp.float32)
+    kp = jax.random.normal(ks[1], (N, G, bs, dh), jnp.float32)
+    vp = jax.random.normal(ks[2], (N, G, bs, dh), jnp.float32)
     k_new = jax.random.normal(ks[3], (B, G, dh), jnp.float32)
     v_new = jax.random.normal(ks[4], (B, G, dh), jnp.float32)
     tables = jnp.asarray(np.arange(1, B * T + 1, dtype=np.int32)
@@ -44,7 +44,7 @@ def test_kernel_fold_matches_scatter_oracle():
     at position ``length`` and attending over lengths+1."""
     q, kp, vp, kn, vn, tables, lengths = _fold_inputs(jax.random.PRNGKey(0))
     B, H = q.shape[:2]
-    gs = H // kp.shape[2]
+    gs = H // kp.shape[1]
     folded = paged_decode_attention(q, kp, vp, tables, lengths,
                                     k_new=kn, v_new=vn)
     ke = jnp.repeat(gather_kv_pages(kp, tables), gs, axis=2)
@@ -90,35 +90,30 @@ def test_stream_supported_matches_plan():
     assert paged_stream_supported(plan) == plan.attn.block_regular
 
 
-def test_stream_support_alignment_gate_compiled(monkeypatch):
-    """Compiled on TPU (no interpret), misaligned tiles must resolve to
-    gather UP FRONT — never a silent in-kernel fallback that the engine
-    would account as streamed."""
-    from repro.kernels.decode_attention import ops as da_ops
+def test_stream_support_alignment_gate_compiled():
+    """The head-major pool hands the compiled kernel whole (bs, dh)
+    planes, so no block size or head width gates streaming (the AOT
+    compiles in test_tpu_compile.py hold the kernel to that); only the
+    stored GQA layout does, and an ineligible explicit request raises
+    instead of degrading to gather."""
+    import dataclasses
+    from repro.kernels.decode_attention.ops import resolve_paged_kernel
     cfg = get_config("smollm-135m").reduced()
     plan = plan_model(cfg, None, (1,), "serve", esl_overlap=False,
                       remat="none", compute_dtype="float32",
                       param_dtype="float32")
-    assert plan.attn.block_regular
-    # interpret mode (CPU): any block size streams
-    assert paged_stream_supported(plan, 16)
-    # explicit interpret flag beats the backend-derived default
-    assert paged_stream_supported(plan, 16, interpret=True)
-    assert not paged_stream_supported(plan, 16, interpret=False)
-    # compiled: LANE-aligned block AND d_head required
-    monkeypatch.setattr(da_ops, "default_interpret", lambda: False)
-    assert not paged_stream_supported(plan, 16)
-    aligned = plan.attn.d_head % 128 == 0
-    assert paged_stream_supported(plan, 128) == aligned
-    # the engine's auto resolution follows the same gate
+    assert plan.attn.block_regular and plan.attn.d_head % 128
+    assert paged_stream_supported(plan)
+    assert resolve_paged_kernel(plan, "auto") == "stream"
     model = build_model(cfg, plan)
     params, _ = model.init(jax.random.PRNGKey(0))
     eng = LPUEngine(model, params, slots=2, max_seq=64, paged=True,
                     block_size=16)
-    assert eng.paged_kernel == "gather"
+    assert eng.paged_kernel == "stream"
+    bad_plan = dataclasses.replace(plan, attn=plan_attention(8, 4, 64, tp=6))
+    assert resolve_paged_kernel(bad_plan, "auto") == "gather"
     with pytest.raises(ValueError):
-        LPUEngine(model, params, slots=2, max_seq=64, paged=True,
-                  block_size=16, paged_kernel="stream")
+        resolve_paged_kernel(bad_plan, "stream")
 
 
 # ---------------------------------------------------------------------------
@@ -193,9 +188,9 @@ def test_decode_layer_stream_matches_gather():
     B, bs, T = 2, 8, 4
     x = jax.random.normal(jax.random.PRNGKey(1), (B, cfg.d_model))
     pool_k = jax.random.normal(jax.random.PRNGKey(2),
-                               (2 * T + 1, bs, a.gp, a.d_head))
+                               (2 * T + 1, a.gp, bs, a.d_head))
     pool_v = jax.random.normal(jax.random.PRNGKey(3),
-                               (2 * T + 1, bs, a.gp, a.d_head))
+                               (2 * T + 1, a.gp, bs, a.d_head))
     tables = jnp.asarray(np.arange(1, 2 * T + 1, dtype=np.int32)
                          .reshape(B, T))
     pos = jnp.asarray([5, 11], jnp.int32)
@@ -282,7 +277,7 @@ def _check_null_block_inert(fill: float, len0: int, len1: int) -> None:
     q, kp, vp, kn, vn, tables, _ = _fold_inputs(jax.random.PRNGKey(5))
     lengths = jnp.asarray([len0, len1], jnp.int32)
     # tail table entries past the valid length point at the null block
-    bs = kp.shape[1]
+    bs = kp.shape[2]
     t_used0, t_used1 = (len0 + bs - 1) // bs, (len1 + bs - 1) // bs
     tb = np.asarray(tables).copy()
     tb[0, t_used0:] = 0
@@ -300,8 +295,11 @@ def _check_null_block_inert(fill: float, len0: int, len1: int) -> None:
 try:
     from hypothesis import given, settings, strategies as st
 
+    # hypothesis demands bounds exactly representable at width=32
+    F32_BIG = float(np.float32(1e30))
+
     @settings(max_examples=20, deadline=None)
-    @given(fill=st.floats(-1e30, 1e30, allow_nan=False,
+    @given(fill=st.floats(-F32_BIG, F32_BIG, allow_nan=False,
                           allow_infinity=False, width=32),
            len0=st.integers(1, 16), len1=st.integers(1, 16))
     def test_null_block_never_contributes(fill, len0, len1):

@@ -132,8 +132,8 @@ def test_per_rank_block_bytes_includes_scales():
 def _quantized_fold_inputs(key, B=2, H=4, G=2, dh=16, bs=8, T=4, N=9):
     ks = jax.random.split(key, 5)
     q = jax.random.normal(ks[0], (B, H, dh), jnp.float32)
-    kp = jax.random.normal(ks[1], (N, bs, G, dh), jnp.float32)
-    vp = jax.random.normal(ks[2], (N, bs, G, dh), jnp.float32)
+    kp = jax.random.normal(ks[1], (N, G, bs, dh), jnp.float32)
+    vp = jax.random.normal(ks[2], (N, G, bs, dh), jnp.float32)
     kq, ksc = quantize_kv_rows(kp, jnp.int8, jnp.float16)
     vq, vsc = quantize_kv_rows(vp, jnp.int8, jnp.float16)
     k_new = jax.random.normal(ks[3], (B, G, dh), jnp.float32)
@@ -150,7 +150,7 @@ def test_stream_dequant_matches_hand_dequantized_dense():
     (q, kq, vq, ksc, vsc, kn, vn, tables,
      lengths) = _quantized_fold_inputs(jax.random.PRNGKey(2))
     B, H = q.shape[:2]
-    gs = H // kq.shape[2]
+    gs = H // kq.shape[1]
     out = paged_decode_attention(q, kq, vq, tables, lengths,
                                  k_new=kn, v_new=vn,
                                  k_scale=ksc, v_scale=vsc)
@@ -184,7 +184,7 @@ def test_copy_pool_block_carries_scales():
     """Copy-on-write duplicates the scale side-arrays with the data —
     a CoW that forgot the scales would dequantize the copy wrongly."""
     key = jax.random.PRNGKey(4)
-    kp = jax.random.normal(key, (1, 5, 4, 2, 8))   # (n_sb, N, bs, G, dh)
+    kp = jax.random.normal(key, (1, 5, 2, 4, 8))   # (n_sb, N, G, bs, dh)
     kq, ksc = quantize_kv_rows(kp, jnp.int8, jnp.float16)
     cache = {"l0": {"k": kq, "v": kq, "k_scale": ksc, "v_scale": ksc}}
     out = copy_pool_block(cache, jnp.int32(2), jnp.int32(4))
@@ -236,7 +236,7 @@ def test_engine_int8_cache_has_scale_leaves_and_honest_bytes(tiny_model):
     l0 = eng.cache["l0"]
     assert set(l0) == {"k", "v", "k_scale", "v_scale"}
     assert l0["k"].dtype == jnp.int8
-    assert l0["k_scale"].dtype == jnp.float16
+    assert l0["k_scale"].dtype == jnp.bfloat16
     assert l0["k_scale"].shape == l0["k"].shape[:-1]
     # reported bytes include the scale side-arrays (per-rank block
     # bytes x blocks is exactly the pool pytree's footprint)

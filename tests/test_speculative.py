@@ -211,7 +211,13 @@ def test_greedy_parity_mid_stream_preemption(tiny_model):
     non-speculative run under the same pressure."""
     model, params = tiny_model
     prompts = _prompts(7, (12, 9, 20))
-    kw = dict(slots=3, max_seq=64, block_size=16, num_blocks=7)
+    # 4 allocatable blocks: the prompts take all 4 at admission (1+1+2)
+    # and every request crosses a block boundary before it finishes, so
+    # both runs MUST preempt.  With 6 blocks the speculative run could
+    # finish its short requests early on accepted drafts and never feel
+    # the pressure, so whether it preempted hung on the drafts the
+    # random weights happen to accept.
+    kw = dict(slots=3, max_seq=64, block_size=16, num_blocks=5)
     ref_eng = LPUEngine(model, params, **kw)
     ref = ref_eng.generate(prompts, max_new_tokens=16)
     eng = LPUEngine(model, params, speculate="ngram", draft_k=4, **kw)
@@ -332,11 +338,14 @@ def test_rejection_sampling_exact_marginals():
     Bounds the TV distance of 20k draws and the accept frequency."""
     V, K = 8, 2
     rows = jax.random.normal(jax.random.PRNGKey(2), (K + 1, V)) * 2.0
-    draft = jnp.asarray([2, 5], jnp.int32)
     temp = jnp.float32(1.0)
     tk, tp_ = jnp.int32(5), jnp.float32(0.85)
     p = np.asarray(jax.nn.softmax(jax.vmap(
         lambda r: _filter_row(r, temp, tk, tp_))(rows), -1))
+    # draft each position's most likely token: the filtered p always
+    # keeps it, so position 1 is conditioned on a frequent accept
+    # whatever rows the installed RNG stream draws
+    draft = jnp.asarray(p[:K].argmax(-1), jnp.int32)
 
     N = 20000
     rngs = jax.random.split(jax.random.PRNGKey(3), N)
@@ -464,8 +473,8 @@ def test_streamline_verify_layer_matches_sequential_decode():
     xs = jax.random.normal(jax.random.PRNGKey(1),
                            (S0 + K1, cfg.d_model))
 
-    pool = {"k": jnp.zeros((T + 1, bs, a.gp, a.d_head)),
-            "v": jnp.zeros((T + 1, bs, a.gp, a.d_head))}
+    pool = {"k": jnp.zeros((T + 1, a.gp, bs, a.d_head)),
+            "v": jnp.zeros((T + 1, a.gp, bs, a.d_head))}
     cache = pool
     ys = []
     for i in range(S0 + K1):
@@ -489,9 +498,14 @@ def test_streamline_verify_layer_matches_sequential_decode():
                                 cfg=cfg, plan=plan, use_kernels=False)
     np.testing.assert_allclose(np.stack(ys[S0:]), np.asarray(y_v),
                                rtol=1e-5, atol=1e-5)
+    # the window's K/V rows come out of a 4-row projection, the
+    # sequential ones out of 1-row projections, which XLA fuses apart:
+    # the pools agree to a few ULP
     for key in ("k", "v"):
-        np.testing.assert_array_equal(np.asarray(cache[key][1:]),
-                                      np.asarray(cache_v[key][1:]))
+        ref = np.asarray(cache[key][1:])
+        np.testing.assert_allclose(
+            np.asarray(cache_v[key][1:]), ref, rtol=0,
+            atol=4 * np.spacing(np.abs(ref).max()))
 
 
 # ---------------------------------------------------------------------------

@@ -58,10 +58,13 @@ def test_decode_layer_paged_matches_dense():
     # scatter the dense cache into a pool through per-request tables
     # (block 0 = null block, requests own disjoint blocks 1..2T)
     tables = np.arange(1, 2 * T + 1, dtype=np.int32).reshape(B, T)
-    pool_k = jnp.zeros((2 * T + 1, bs, a.gp, a.d_head))
-    pool_v = jnp.zeros((2 * T + 1, bs, a.gp, a.d_head))
-    chunks_k = np.asarray(k0).reshape(B, T, bs, a.gp, a.d_head)
-    chunks_v = np.asarray(v0).reshape(B, T, bs, a.gp, a.d_head)
+    pool_k = jnp.zeros((2 * T + 1, a.gp, bs, a.d_head))
+    pool_v = jnp.zeros((2 * T + 1, a.gp, bs, a.d_head))
+    # seq-major rows -> the pool's head-major (gp, bs, dh) blocks
+    chunks_k = np.asarray(k0).reshape(B, T, bs, a.gp,
+                                      a.d_head).swapaxes(2, 3)
+    chunks_v = np.asarray(v0).reshape(B, T, bs, a.gp,
+                                      a.d_head).swapaxes(2, 3)
     pool_k = pool_k.at[tables].set(chunks_k)
     pool_v = pool_v.at[tables].set(chunks_v)
     y_pg, c_pg = decode_layer(p, x, {"k": pool_k, "v": pool_v}, pos,
@@ -71,7 +74,7 @@ def test_decode_layer_paged_matches_dense():
     # the new token's KV landed in the right physical block slot
     for b in range(B):
         blk, off = tables[b, int(pos[b]) // bs], int(pos[b]) % bs
-        assert np.array_equal(np.asarray(c_pg["k"][blk, off]),
+        assert np.array_equal(np.asarray(c_pg["k"][blk, :, off]),
                               np.asarray(c_ref["k"][b, int(pos[b])]))
 
 

@@ -7,11 +7,10 @@ http(s) links and bare in-page anchors are skipped — CI has no network).
 Also verifies the `file:line` anchors used by docs/ARCHITECTURE.md:
 the file part must exist and the line number must be within the file.
 
-CHANGES.md and ISSUE.md are checked by default (and by the docs CI job)
-so stale `file:line` references in the PR log rot loudly instead of
-silently.
+CHANGES.md is checked by default (and by the docs CI job) so stale
+`file:line` references in the PR log rot loudly instead of silently.
 
-    python tools/check_links.py README.md docs/ CHANGES.md ISSUE.md
+    python tools/check_links.py README.md docs/ CHANGES.md
 """
 from __future__ import annotations
 
@@ -62,7 +61,7 @@ def check(root: Path, files) -> int:
 
 
 def main() -> int:
-    args = sys.argv[1:] or ["README.md", "docs", "CHANGES.md", "ISSUE.md"]
+    args = sys.argv[1:] or ["README.md", "docs", "CHANGES.md"]
     root = Path.cwd()
     files = list(md_files(args))
     bad = check(root, files)
