@@ -1,0 +1,9 @@
+"""Benchmark tests run on the CPU: they put the benchmark's own
+directory and the program's sources on the path."""
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+for p in (str(BENCH), str(BENCH.parent / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
