@@ -58,7 +58,8 @@ route-to-the-ring-whose-prefix-cache-owns-the-prompt),
 steps_per_sync each step from a measured EWMA seeded by the analytic
 step-time prior), ``--max-pending N`` (admission bound with structured
 backpressure), ``--tracker PATH`` (jsonl telemetry: per-window
-EngineStats deltas + per-request TTFT / ms-per-token records).  See
+EngineStats deltas, per-request TTFT / ms-per-token records, and the
+engine's spans, ``span`` records).  See
 docs/serving.md "Async front end, SLO scheduling & telemetry".
 """
 from __future__ import annotations
@@ -95,7 +96,7 @@ def serve_async(engine, cfg, args, prompts, sp):
 
     from repro.serving.budget import BudgetScheduler
     from repro.serving.frontend import AdmissionRejected, AsyncFrontend
-    from repro.serving.tracker import JsonlTracker
+    from repro.serving.tracker import JsonlTracker, SpanRecorder
 
     budget = None
     if args.budget_ms > 0:
@@ -105,6 +106,7 @@ def serve_async(engine, cfg, args, prompts, sp):
         budget = BudgetScheduler(args.budget_ms, prior_step_s=prior,
                                  max_chunk=args.max_seq)
     tracker = JsonlTracker(args.tracker) if args.tracker else None
+    spans = SpanRecorder().attach(engine) if tracker is not None else None
     retries = 0
 
     async def go():
@@ -123,7 +125,11 @@ def serve_async(engine, cfg, args, prompts, sp):
             outs = [await s.drain() for s in streams]
         return fe, streams, outs
 
-    fe, streams, outs = asyncio.run(go())
+    try:
+        fe, streams, outs = asyncio.run(go())
+    finally:
+        if spans is not None:
+            spans.detach()
     tl = [s.timeline for s in streams if s.timeline.t_first is not None]
     ttft = sorted(t.ttft_ms for t in tl)
     mpt = sorted(t.ms_per_token for t in tl if t.tokens >= 2)
@@ -142,8 +148,8 @@ def serve_async(engine, cfg, args, prompts, sp):
               f"({budget.observed_windows} windows, "
               f"{budget.observed_chunks} chunks observed)")
     if tracker is not None:
-        print(f"[serve] tracker: {tracker.written} records -> "
-              f"{tracker.path}")
+        print(f"[serve] tracker: {tracker.written} records "
+              f"({len(spans.spans)} spans) -> {tracker.path}")
     return outs
 
 
@@ -253,9 +259,10 @@ def main():
                          "AdmissionRejected (0 = unbounded; forces the "
                          "async frontend path)")
     ap.add_argument("--tracker", default="",
-                    help="write per-window EngineStats deltas and "
-                         "per-request TTFT/ms-per-token records to this "
-                         "jsonl file (forces the async frontend path)")
+                    help="write per-window EngineStats deltas, "
+                         "per-request TTFT/ms-per-token records and the "
+                         "engine's spans to this jsonl file (forces the "
+                         "async frontend path)")
     args = ap.parse_args()
 
     print(f"[serve] compile cache: {use_compile_cache()}")

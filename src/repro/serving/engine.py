@@ -113,8 +113,19 @@ from repro.serving.sampler import (SamplingParams, sample_batched,
                                    speculative_verify_sharded,
                                    split_spec_rng_chain)
 from repro.serving.scheduler import RingRouter, Scheduler, SeqSlot
+from repro.serving.tracker import NO_SPANS
 
 StreamCB = Callable[[int, int], None]   # (request_id, token)
+
+
+def _program(name: str, fn: Callable, **jit_kw) -> Callable:
+    """``jax.jit`` of ``fn`` under ``name``: the program lowers as
+    ``module @jit_<name>``, which is how the profiler's trace names it
+    (a bound method or a ``partial`` would come out ``jit__unknown``)."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, **jit_kw)
 
 
 @dataclass
@@ -468,6 +479,9 @@ class LPUEngine:
         self.sched = Scheduler(slots, max_seq, pool, min_bucket,
                                prefix=self.prefix)
         self.stats = EngineStats()
+        # spans at the layer boundaries of step(): off until a
+        # SpanRecorder attaches (serving/tracker.py)
+        self.spans = NO_SPANS
         # cumulative bases for the stats fields ASSIGNED (not
         # incremented) from subsystem counters — scheduler preemptions,
         # pool evictions, prefix index counters.  reset() rebuilds those
@@ -483,14 +497,16 @@ class LPUEngine:
         self._chunk_rr = -1           # admit_seq of the last chunk served
         self._buckets_traced: Set[int] = set()
         self._window_jits: Dict[int, Callable] = {}
-        self._sample_one = jax.jit(self._sample_one_fn)
+        self._sample_one = _program("lpu_sample_first", self._sample_one_fn)
         if mesh is None:
-            self._decode = jax.jit(self._decode_fn)
-            self._prefill = jax.jit(self._prefill_fn)
-            self._prefill_chunk_fn = jax.jit(self._chunk_fn)
-            self._write_pages = jax.jit(scatter_prefill_pages)
-            self._write_dense = jax.jit(scatter_prefill_dense)
-            self._copy_block = jax.jit(copy_pool_block)
+            self._decode = _program("lpu_decode", self._decode_fn)
+            self._prefill = _program("lpu_prefill", self._prefill_fn)
+            self._prefill_chunk_fn = _program("lpu_chunk", self._chunk_fn)
+            self._write_pages = _program("lpu_write_pages",
+                                         scatter_prefill_pages)
+            self._write_dense = _program("lpu_write_dense",
+                                         scatter_prefill_dense)
+            self._copy_block = _program("lpu_copy_block", copy_pool_block)
         else:
             self._build_mesh_fns()
 
@@ -686,8 +702,8 @@ class LPUEngine:
         """The jitted ``S``-step fused window (one trace per S)."""
         fn = self._window_jits.get(S)
         if fn is None:
-            fn = (jax.jit(partial(self._window_fn, S)) if self.mesh is None
-                  else self._build_mesh_window(S))
+            fn = (_program("lpu_decode_window", partial(self._window_fn, S))
+                  if self.mesh is None else self._build_mesh_window(S))
             self._window_jits[S] = fn
         return fn
 
@@ -786,9 +802,9 @@ class LPUEngine:
             if self.mesh is not None:
                 fn = self._build_mesh_verify(K)
             elif self.sampling == "fused":
-                fn = jax.jit(partial(self._verify_fused_fn, K))
+                fn = _program("lpu_verify", partial(self._verify_fused_fn, K))
             else:
-                fn = jax.jit(self._verify_fwd)
+                fn = _program("lpu_verify", self._verify_fwd)
             self._verify_jits[key] = fn
         return fn
 
@@ -821,26 +837,26 @@ class LPUEngine:
         self._pf_zero: Dict[int, object] = {}   # bucket -> zeroed cache
 
         if self.paged:
-            def dec(params, cache, tokens, positions, tables):
+            def lpu_decode(params, cache, tokens, positions, tables):
                 return self._decode_fn(params, cache, tokens, positions,
                                        tables)
             dec_sm = jax.jit(shard_map(
-                dec, mesh=mesh,
+                lpu_decode, mesh=mesh,
                 in_specs=(specs, cspecs, P(None, None), P(None),
                           P(None, None)),
                 out_specs=(P(None, m), cspecs), check_vma=False))
             self._decode = dec_sm
         else:
-            def dec_d(params, cache, tokens, positions):
+            def lpu_decode(params, cache, tokens, positions):
                 return self._decode_fn(params, cache, tokens, positions,
                                        None)
             dec_sm = jax.jit(shard_map(
-                dec_d, mesh=mesh,
+                lpu_decode, mesh=mesh,
                 in_specs=(specs, cspecs, P(None, None), P(None)),
                 out_specs=(P(None, m), cspecs), check_vma=False))
             self._decode = lambda p, c, t, pos, tables: dec_sm(p, c, t, pos)
 
-        def pre(params, cache0, tokens, true_len):
+        def lpu_prefill(params, cache0, tokens, true_len):
             B, S = tokens.shape
             positions = jnp.broadcast_to(jnp.arange(S), (1, S))
             logits, new_cache, _ = self.model.forward(
@@ -851,7 +867,7 @@ class LPUEngine:
             return row, new_cache
 
         pre_sm = jax.jit(shard_map(
-            pre, mesh=mesh,
+            lpu_prefill, mesh=mesh,
             in_specs=(specs, pf_cspecs, P(None, None), P()),
             out_specs=(P(m), pf_cspecs), check_vma=False))
 
@@ -873,20 +889,20 @@ class LPUEngine:
             # rank, same block ids everywhere), tokens/table/offsets are
             # replicated host state and the logits row comes out
             # vocab-sharded exactly like the monolithic prefill's.
-            def chunk(params, cache, tokens, table, start, n_valid):
+            def lpu_chunk(params, cache, tokens, table, start, n_valid):
                 return self._chunk_fn(params, cache, tokens, table,
                                       start, n_valid)
             self._prefill_chunk_fn = jax.jit(shard_map(
-                chunk, mesh=mesh,
+                lpu_chunk, mesh=mesh,
                 in_specs=(specs, cspecs, P(None, None), P(None), P(),
                           P()),
                 out_specs=(P(m), cspecs), check_vma=False))
-        self._write_pages = jax.jit(scatter_prefill_pages,
+        self._write_pages = _program("lpu_write_pages", scatter_prefill_pages,
+                                     out_shardings=cspecs_named)
+        self._write_dense = _program("lpu_write_dense", scatter_prefill_dense,
+                                     out_shardings=cspecs_named)
+        self._copy_block = _program("lpu_copy_block", copy_pool_block,
                                     out_shardings=cspecs_named)
-        self._write_dense = jax.jit(scatter_prefill_dense,
-                                    out_shardings=cspecs_named)
-        self._copy_block = jax.jit(copy_pool_block,
-                                   out_shardings=cspecs_named)
 
     def _build_mesh_window(self, S: int) -> Callable:
         """shard_map-wrapped fused window over the model ring.
@@ -905,24 +921,25 @@ class LPUEngine:
         out_specs = (P(None, None), P(None, None), cspecs) + (rep,) * 5
 
         if self.paged:
-            def win(params, cache, tables, last, pos, n_out, alive, rng,
-                    temps, top_ks, top_ps, max_new, poison):
+            def lpu_decode_window(params, cache, tables, last, pos, n_out,
+                                  alive, rng, temps, top_ks, top_ps,
+                                  max_new, poison):
                 return self._window_fn(S, params, cache, tables, last,
                                        pos, n_out, alive, rng, temps,
                                        top_ks, top_ps, max_new, poison)
             return jax.jit(shard_map(
-                win, mesh=mesh,
+                lpu_decode_window, mesh=mesh,
                 in_specs=(specs, cspecs, P(None, None)) + (rep,) * 9
                 + (P(),),
                 out_specs=out_specs, check_vma=False))
 
-        def win_d(params, cache, last, pos, n_out, alive, rng,
-                  temps, top_ks, top_ps, max_new, poison):
+        def lpu_decode_window(params, cache, last, pos, n_out, alive, rng,
+                              temps, top_ks, top_ps, max_new, poison):
             return self._window_fn(S, params, cache, None, last, pos,
                                    n_out, alive, rng, temps, top_ks,
                                    top_ps, max_new, poison)
         sm = jax.jit(shard_map(
-            win_d, mesh=mesh,
+            lpu_decode_window, mesh=mesh,
             in_specs=(specs, cspecs) + (rep,) * 9 + (P(),),
             out_specs=out_specs, check_vma=False))
 
@@ -948,22 +965,22 @@ class LPUEngine:
         specs, cspecs = self._mesh_specs
         rep1, rep2 = P(None), P(None, None)
         if self.sampling == "fused":
-            def ver(params, cache, tokens, positions, tables, lens,
-                    draft, rng, temps, top_ks, top_ps, alive):
+            def lpu_verify(params, cache, tokens, positions, tables, lens,
+                           draft, rng, temps, top_ks, top_ps, alive):
                 return self._verify_fused_fn(
                     K, params, cache, tokens, positions, tables, lens,
                     draft, rng, temps, top_ks, top_ps, alive)
             return jax.jit(shard_map(
-                ver, mesh=mesh,
+                lpu_verify, mesh=mesh,
                 in_specs=(specs, cspecs, rep2, rep2, rep2, rep1, rep2,
                           rep1, rep1, rep1, rep1, rep1),
                 out_specs=(rep2, rep1, cspecs, rep1), check_vma=False))
 
-        def ver_h(params, cache, tokens, positions, tables, lens):
+        def lpu_verify(params, cache, tokens, positions, tables, lens):
             return self._verify_fwd(params, cache, tokens, positions,
                                     tables, lens)
         return jax.jit(shard_map(
-            ver_h, mesh=mesh,
+            lpu_verify, mesh=mesh,
             in_specs=(specs, cspecs, rep2, rep2, rep2, rep1),
             out_specs=(P(None, m), cspecs), check_vma=False))
 
@@ -995,8 +1012,11 @@ class LPUEngine:
             self.stats.host_syncs += 1
             self.stats.prefill_syncs += 1
             self.stats.bytes_to_host += 4
-            return int(tok)
-        row_np = np.asarray(row)
+            with self.spans.span("lpu.wait", bytes=4):
+                return int(tok)
+        with self.spans.span("lpu.wait") as sp:
+            row_np = np.asarray(row)
+            sp.count(bytes=row_np.nbytes)
         self.stats.host_syncs += 1
         self.stats.bytes_to_host += row_np.nbytes
         before = self.stats.host_syncs
@@ -1063,21 +1083,24 @@ class LPUEngine:
             return self._prefill_tail(seq, tokens)
         bucket = (self.sched.bucket(len(tokens)) if self.bucketed
                   else len(tokens))
-        buf = np.zeros((1, bucket), np.int32)
-        buf[0, :len(tokens)] = tokens
-        row, pc = self._prefill(self.params, jnp.asarray(buf),
-                                jnp.int32(len(tokens)))
-        self._buckets_traced.add(bucket)
-        self.stats.prefills += 1
-        slot = self.sched.slot_of(seq)
-        if self.paged:
-            table = np.zeros((bucket // self.block_size,), np.int32)
-            table[:len(seq.blocks)] = seq.blocks
-            self.cache = self._write_pages(self.cache, pc,
-                                           jnp.asarray(table))
-        else:
-            self.cache = self._write_dense(self.cache, pc, jnp.int32(slot))
-        return self._finish_prefill(seq, row)
+        with self.spans.span("lpu.prefill", rid=req.rid, tokens=len(tokens),
+                             bucket=bucket):
+            buf = np.zeros((1, bucket), np.int32)
+            buf[0, :len(tokens)] = tokens
+            row, pc = self._prefill(self.params, jnp.asarray(buf),
+                                    jnp.int32(len(tokens)))
+            self._buckets_traced.add(bucket)
+            self.stats.prefills += 1
+            slot = self.sched.slot_of(seq)
+            if self.paged:
+                table = np.zeros((bucket // self.block_size,), np.int32)
+                table[:len(seq.blocks)] = seq.blocks
+                self.cache = self._write_pages(self.cache, pc,
+                                               jnp.asarray(table))
+            else:
+                self.cache = self._write_dense(self.cache, pc,
+                                               jnp.int32(slot))
+            return self._finish_prefill(seq, row)
 
     def _prefill_tail(self, seq: SeqSlot, tokens: List[int]
                       ) -> Optional[Request]:
@@ -1094,17 +1117,19 @@ class LPUEngine:
         n = len(tokens)
         start = seq.cached
         C = self.sched.bucket(n - start)
-        self._ensure_writable(seq, start, n)
-        buf = np.zeros((1, C), np.int32)
-        buf[0, :n - start] = tokens[start:n]
-        table = np.zeros((self.table_len,), np.int32)
-        table[:len(seq.blocks)] = seq.blocks
-        row, self.cache = self._prefill_chunk_fn(
-            self.params, self.cache, jnp.asarray(buf), jnp.asarray(table),
-            jnp.int32(start), jnp.int32(n - start))
-        self._buckets_traced.add(("chunk", C))
-        self.stats.prefills += 1
-        return self._finish_prefill(seq, row)
+        with self.spans.span("lpu.prefill", rid=seq.req.rid,
+                             tokens=n - start, bucket=C):
+            self._ensure_writable(seq, start, n)
+            buf = np.zeros((1, C), np.int32)
+            buf[0, :n - start] = tokens[start:n]
+            table = np.zeros((self.table_len,), np.int32)
+            table[:len(seq.blocks)] = seq.blocks
+            row, self.cache = self._prefill_chunk_fn(
+                self.params, self.cache, jnp.asarray(buf),
+                jnp.asarray(table), jnp.int32(start), jnp.int32(n - start))
+            self._buckets_traced.add(("chunk", C))
+            self.stats.prefills += 1
+            return self._finish_prefill(seq, row)
 
     def _ensure_writable(self, seq: SeqSlot, lo: int, hi: int,
                          allow_preempt: bool = True) -> bool:
@@ -1133,7 +1158,8 @@ class LPUEngine:
             old = seq.blocks[li]
             if self.sched.pool.ref[old] <= 1:
                 continue
-            new, _ = self.sched.cow_alloc(seq, allow_preempt)
+            new, victims = self.sched.cow_alloc(seq, allow_preempt)
+            self._requeued(victims)
             if new is None:
                 return False
             self.cache = self._copy_block(self.cache, jnp.int32(old),
@@ -1186,23 +1212,25 @@ class LPUEngine:
         C = self.prefill_chunk
         start = seq.prefilled
         n_valid = min(C, len(tokens) - start)
-        buf = np.zeros((1, C), np.int32)
-        # table is built AFTER the CoW guard: a split swaps block ids
-        assert not self._cow_pending(seq, start, start + n_valid)
-        buf[0, :n_valid] = tokens[start:start + n_valid]
-        table = np.zeros((self.table_len,), np.int32)
-        table[:len(seq.blocks)] = seq.blocks
-        row, self.cache = self._prefill_chunk_fn(
-            self.params, self.cache, jnp.asarray(buf), jnp.asarray(table),
-            jnp.int32(start), jnp.int32(n_valid))
-        seq.prefilled = start + n_valid
-        seq.pos = seq.prefilled
-        self._buckets_traced.add(("chunk", C))
-        self.stats.prefills += 1
-        self.stats.prefill_chunks += 1
-        if seq.prefilling:
-            return None              # more chunks next step
-        return self._finish_prefill(seq, row)
+        with self.spans.span("lpu.prefill", rid=req.rid, tokens=n_valid,
+                             bucket=C):
+            buf = np.zeros((1, C), np.int32)
+            # table is built AFTER the CoW guard: a split swaps block ids
+            assert not self._cow_pending(seq, start, start + n_valid)
+            buf[0, :n_valid] = tokens[start:start + n_valid]
+            table = np.zeros((self.table_len,), np.int32)
+            table[:len(seq.blocks)] = seq.blocks
+            row, self.cache = self._prefill_chunk_fn(
+                self.params, self.cache, jnp.asarray(buf),
+                jnp.asarray(table), jnp.int32(start), jnp.int32(n_valid))
+            seq.prefilled = start + n_valid
+            seq.pos = seq.prefilled
+            self._buckets_traced.add(("chunk", C))
+            self.stats.prefills += 1
+            self.stats.prefill_chunks += 1
+            if seq.prefilling:
+                return None              # more chunks next step
+            return self._finish_prefill(seq, row)
 
     def _admit_and_chunk(self) -> List[Request]:
         """Chunked-mode admission: admit while slots + first-chunk
@@ -1219,9 +1247,21 @@ class LPUEngine:
         must not hold the short one's first token hostage for ten
         steps — exactly the head-of-line blocking chunking exists to
         remove."""
-        finished: List[Request] = []
-        while self.sched.admit_next(chunk=self.prefill_chunk) is not None:
-            pass
+        with self.spans.span("lpu.schedule") as sp:
+            admitted = 0
+            while self._admit(self.prefill_chunk) is not None:
+                admitted += 1
+            seq = self._next_chunk()
+            sp.count(admitted=admitted)
+        if seq is None:
+            return []
+        done = self._run_prefill_chunk(seq)      # ONE chunk per step
+        return [] if done is None else [done]
+
+    def _next_chunk(self) -> Optional[SeqSlot]:
+        """The prefilling sequence whose next chunk runs this step, its
+        blocks reserved (and split copy-on-write); None when no chunk
+        can run (pool pressure)."""
         cands = self.sched.prefilling()
         # rotate so the scan starts just after the last sequence served
         # (each candidate probed at most once per step)
@@ -1234,17 +1274,29 @@ class LPUEngine:
                 allow_preempt=allow_preempt)
             if got is None:
                 continue             # pool pressure: try the next seq
+            self._requeued(got)
             nxt = min(seq.prefilled + self.prefill_chunk,
                       seq.prefill_target)
             if not self._ensure_writable(seq, seq.prefilled, nxt,
                                          allow_preempt=allow_preempt):
                 continue             # no CoW block free: try the next seq
             self._chunk_rr = seq.admit_seq
-            done = self._run_prefill_chunk(seq)
-            if done is not None:
-                finished.append(done)
-            break                    # ONE chunk per step
-        return finished
+            return seq
+        return None
+
+    def _admit(self, chunk: int = 0) -> Optional[SeqSlot]:
+        """:meth:`Scheduler.admit_next`, ending the admitted request's
+        queue span."""
+        seq = self.sched.admit_next(chunk=chunk)
+        if seq is not None:
+            self.spans.admitted(seq.req.rid)
+        return seq
+
+    def _requeued(self, victims: Sequence[SeqSlot]) -> None:
+        """Preempted sequences went back to the queue: their requests'
+        queue spans open again."""
+        for seq in victims:
+            self.spans.queued(seq.req.rid)
 
     # -- public API ----------------------------------------------------
 
@@ -1267,6 +1319,7 @@ class LPUEngine:
                 f"{self.max_seq}: no room to decode")
         self._rid = max(self._rid, req.rid) + 1
         self.sched.submit(req)
+        self.spans.queued(req.rid)
         return req.rid
 
     def has_work(self) -> bool:
@@ -1353,11 +1406,16 @@ class LPUEngine:
         the step — that interleave is what keeps active streams
         producing while a long prompt admits.  Returns requests
         finished this round."""
-        t0 = time.time()
-        try:
-            return self._step()
-        finally:
-            self.stats.wall += time.time() - t0
+        st = self.stats
+        prefills, steps = st.prefills, st.steps
+        t0 = time.perf_counter()
+        with self.spans.span("lpu.step", ring=self.ring_id) as sp:
+            try:
+                return self._step()
+            finally:
+                st.wall += time.perf_counter() - t0
+                sp.count(prefills=st.prefills - prefills,
+                         steps=st.steps - steps)
 
     def _step(self) -> List[Request]:
         self._step_no += 1
@@ -1372,14 +1430,40 @@ class LPUEngine:
             finished += self._admit_and_chunk()
         else:
             while True:
-                seq = self.sched.admit_next()
+                with self.spans.span("lpu.schedule") as sp:
+                    seq = self._admit()
+                    sp.count(admitted=int(seq is not None))
                 if seq is None:
                     break
                 done = self._do_prefill(seq)
                 if done is not None:
                     finished.append(done)
-        finished += self._harvest_rejections()
-        self.sched.ensure_decode_capacity()     # may preempt (recompute)
+        with self.spans.span("lpu.schedule") as sp:
+            preempted = self.stats.preemptions
+            finished += self._harvest_rejections()
+            # may preempt (recompute)
+            self._requeued(self.sched.ensure_decode_capacity())
+            self._mirror_counters()
+            sp.count(preempted=self.stats.preemptions - preempted)
+        live = self.sched.num_decoding()
+        if live == 0:
+            return finished
+        with self.spans.span("lpu.decode", live=live) as sp:
+            steps = self.stats.steps
+            if self.drafter is not None:
+                done, window = self._spec_decode_round()
+            elif self.sampling == "fused":
+                done, window = self._fused_decode_round()
+            else:
+                done, window = self._host_decode_step()
+            sp.count(steps=self.stats.steps - steps, window=window)
+        finished += done
+        self.stats.prefill_traces = len(self._buckets_traced)
+        return finished
+
+    def _mirror_counters(self) -> None:
+        """Copy the scheduler's, pool's and prefix index's counters into
+        ``stats`` over the bases banked at :meth:`reset`."""
         base = self._ctr_base
         self.stats.preemptions = base["preemptions"] \
             + self.sched.preemptions
@@ -1396,16 +1480,6 @@ class LPUEngine:
                 + self.prefix.hit_blocks
             self.stats.prefill_tokens_saved = base["prefill_tokens_saved"] \
                 + self.prefix.tokens_saved
-        if self.sched.num_decoding() == 0:
-            return finished
-        if self.drafter is not None:
-            finished += self._spec_decode_round()
-        elif self.sampling == "fused":
-            finished += self._fused_decode_round()
-        else:
-            finished += self._host_decode_step()
-        self.stats.prefill_traces = len(self._buckets_traced)
-        return finished
 
     def _harvest_rejections(self) -> List[Request]:
         """Surface scheduler admission rejections (request can NEVER
@@ -1426,23 +1500,29 @@ class LPUEngine:
 
     # -- host-sampled decode (the pre-fusion baseline) -----------------
 
-    def _host_decode_step(self) -> List[Request]:
+    def _host_decode_step(self) -> Tuple[List[Request], int]:
         """One decode step, sampling on host: the full (slots, vocab)
         logits tensor crosses to the host every token — the
         serialization the fused path removes (kept as the parity oracle
-        and the "synced" row of serving_bench)."""
-        self._refresh_tables()
-        toks = np.zeros((self.slots, 1), np.int32)
-        pos = np.zeros((self.slots,), np.int32)
-        for slot, seq in enumerate(self.sched.active):
-            if seq is not None and not seq.prefilling:
-                toks[slot, 0] = seq.last_token
-                pos[slot] = seq.pos
-        tables = (jnp.asarray(self.block_tables) if self.paged else None)
-        logits, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(toks), jnp.asarray(pos),
-            tables)
-        logits_np = np.asarray(logits)
+        and the "synced" row of serving_bench).  Returns the requests
+        it finished and its window (1)."""
+        with self.spans.span("lpu.prepare"):
+            self._refresh_tables()
+            toks = np.zeros((self.slots, 1), np.int32)
+            pos = np.zeros((self.slots,), np.int32)
+            for slot, seq in enumerate(self.sched.active):
+                if seq is not None and not seq.prefilling:
+                    toks[slot, 0] = seq.last_token
+                    pos[slot] = seq.pos
+        with self.spans.span("lpu.dispatch", window=1):
+            tables = (jnp.asarray(self.block_tables) if self.paged
+                      else None)
+            logits, self.cache = self._decode(
+                self.params, self.cache, jnp.asarray(toks),
+                jnp.asarray(pos), tables)
+        with self.spans.span("lpu.wait") as sp:
+            logits_np = np.asarray(logits)
+            sp.count(bytes=logits_np.nbytes)
         self.stats.host_syncs += 1
         self.stats.bytes_to_host += logits_np.nbytes
         if self._poison_next:
@@ -1462,21 +1542,24 @@ class LPUEngine:
         finished: List[Request] = []
         self.stats.steps += 1
         self.stats.slot_steps += self.slots
-        for slot, seq in enumerate(self.sched.active):
-            if seq is None or seq.prefilling:
-                continue
-            req = seq.req
-            self.stats.busy_slot_steps += 1
-            self.stats.tokens += 1
-            tok = self._sample(logits_np[slot], logits[slot], req.params)
-            req.out.append(tok)
-            seq.pos += 1
-            seq.last_token = tok
-            if req.stream_cb:
-                req.stream_cb(req.rid, tok)
-            if self._should_finish(seq, tok):
-                finished.append(self._finish(seq))
-        return finished
+        with self.spans.span("lpu.reconcile") as sp:
+            tokens = self.stats.tokens
+            for slot, seq in enumerate(self.sched.active):
+                if seq is None or seq.prefilling:
+                    continue
+                req = seq.req
+                self.stats.busy_slot_steps += 1
+                self.stats.tokens += 1
+                tok = self._sample(logits_np[slot], logits[slot], req.params)
+                req.out.append(tok)
+                seq.pos += 1
+                seq.last_token = tok
+                if req.stream_cb:
+                    req.stream_cb(req.rid, tok)
+                if self._should_finish(seq, tok):
+                    finished.append(self._finish(seq))
+            sp.count(tokens=self.stats.tokens - tokens)
+        return finished, 1
 
     # -- fused decode: multi-step windows + double-buffered dispatch ---
 
@@ -1544,11 +1627,13 @@ class LPUEngine:
         device carry).  Consumes a pending chaos ``nan`` event: the
         poison flag rides into the program as a traced bool, so the
         fault happens on device and only the guard can catch it."""
-        tables = (jnp.asarray(self.block_tables) if self.paged else None)
         poison = np.bool_(self._poison_next)
         self._poison_next = False
-        out = self._window(win)(self.params, self.cache, tables, *carry,
-                                self.rng, *samp, poison)
+        with self.spans.span("lpu.dispatch", window=win):
+            tables = (jnp.asarray(self.block_tables) if self.paged
+                      else None)
+            out = self._window(win)(self.params, self.cache, tables,
+                                    *carry, self.rng, *samp, poison)
         tok_mat, ok_mat, self.cache, last, pos, n_out, alive, self.rng \
             = out
         snapshot = [s is not None and not s.prefilling
@@ -1570,43 +1655,48 @@ class LPUEngine:
         after the fault never reach a request, so a recovered stream
         can be bit-identical to a fault-free run."""
         win, tok_mat, ok_mat, dispatch_active = handle
-        toks = np.asarray(tok_mat)                     # (win, slots)
-        oks = np.asarray(ok_mat)                       # (win, slots) bool
+        with self.spans.span("lpu.wait") as sp:
+            toks = np.asarray(tok_mat)                 # (win, slots)
+            oks = np.asarray(ok_mat)                   # (win, slots) bool
+            sp.count(bytes=toks.nbytes + oks.nbytes)
         self.stats.host_syncs += 1
         self.stats.bytes_to_host += toks.nbytes + oks.nbytes
-        finished: List[Request] = []
-        for s in range(win):
-            bad = [slot for slot in range(self.slots)
-                   if dispatch_active[slot] and not oks[s, slot]]
-            if bad:
-                raise RingFailure(
-                    "nan_logits", self._step_no, self.ring_id,
-                    {"window_step": s, "slots": bad})
-            if self.sched.num_decoding() == 0:
-                self.stats.overrun_tokens += \
-                    (win - s) * sum(dispatch_active)
-                break
-            self.stats.steps += 1
-            self.stats.slot_steps += self.slots
-            for slot, seq in enumerate(self.sched.active):
-                if seq is None or seq.prefilling:
-                    if dispatch_active[slot]:
-                        self.stats.overrun_tokens += 1
-                    continue
-                req = seq.req
-                self.stats.busy_slot_steps += 1
-                self.stats.tokens += 1
-                tok = int(toks[s, slot])
-                req.out.append(tok)
-                seq.pos += 1
-                seq.last_token = tok
-                if req.stream_cb:
-                    req.stream_cb(req.rid, tok)
-                if self._should_finish(seq, tok):
-                    finished.append(self._finish(seq))
+        with self.spans.span("lpu.reconcile") as sp:
+            tokens = self.stats.tokens
+            finished: List[Request] = []
+            for s in range(win):
+                bad = [slot for slot in range(self.slots)
+                       if dispatch_active[slot] and not oks[s, slot]]
+                if bad:
+                    raise RingFailure(
+                        "nan_logits", self._step_no, self.ring_id,
+                        {"window_step": s, "slots": bad})
+                if self.sched.num_decoding() == 0:
+                    self.stats.overrun_tokens += \
+                        (win - s) * sum(dispatch_active)
+                    break
+                self.stats.steps += 1
+                self.stats.slot_steps += self.slots
+                for slot, seq in enumerate(self.sched.active):
+                    if seq is None or seq.prefilling:
+                        if dispatch_active[slot]:
+                            self.stats.overrun_tokens += 1
+                        continue
+                    req = seq.req
+                    self.stats.busy_slot_steps += 1
+                    self.stats.tokens += 1
+                    tok = int(toks[s, slot])
+                    req.out.append(tok)
+                    seq.pos += 1
+                    seq.last_token = tok
+                    if req.stream_cb:
+                        req.stream_cb(req.rid, tok)
+                    if self._should_finish(seq, tok):
+                        finished.append(self._finish(seq))
+            sp.count(tokens=self.stats.tokens - tokens)
         return finished
 
-    def _fused_decode_round(self) -> List[Request]:
+    def _fused_decode_round(self) -> Tuple[List[Request], int]:
         """One fused decode round: up to two pipelined windows.
 
         Window size is ``steps_per_sync`` whenever no admission is
@@ -1617,26 +1707,30 @@ class LPUEngine:
         dispatched off window k's on-device carry BEFORE blocking on
         window k's tokens — the device-side finish masking makes the
         chained carry exact, so the speculation can waste compute
-        (overrun tokens) but never produce wrong ones.
+        (overrun tokens) but never produce wrong ones.  Returns the
+        requests it finished and its window size.
         """
         S = self.steps_per_sync
-        win = S if (S > 1 and not self._admission_waiting()
-                    and self.sched.reserve_lookahead(S)) else 1
-        self._refresh_tables()
-        carry, samp = self._slot_state()
-        h1, dev_carry = self._dispatch_window(win, carry, samp)
-        h2 = None
-        if self.pipeline and not self.sched.queue \
-                and self._may_survive(win) \
-                and self.sched.reserve_lookahead(2 * win):
+        with self.spans.span("lpu.prepare"):
+            win = S if (S > 1 and not self._admission_waiting()
+                        and self.sched.reserve_lookahead(S)) else 1
             self._refresh_tables()
-            h2, _ = self._dispatch_window(win, dev_carry, samp)
+            carry, samp = self._slot_state()
+        h1, dev_carry = self._dispatch_window(win, carry, samp)
+        with self.spans.span("lpu.prepare"):
+            ahead = self.pipeline and not self.sched.queue \
+                and self._may_survive(win) \
+                and self.sched.reserve_lookahead(2 * win)
+            if ahead:
+                self._refresh_tables()
+        h2 = (self._dispatch_window(win, dev_carry, samp)[0] if ahead
+              else None)
         finished = self._reconcile(h1)
         if h2 is not None:
             finished += self._reconcile(h2)
-        return finished
+        return finished, win
 
-    def _spec_decode_round(self) -> List[Request]:
+    def _spec_decode_round(self) -> Tuple[List[Request], int]:
         """One draft-and-verify speculative round.
 
         Per decode-ready slot the drafter proposes up to ``draft_k``
@@ -1656,7 +1750,8 @@ class LPUEngine:
         Rounds that cannot speculate — no proposal anywhere, no
         head-room before max_seq, or a lookahead-block shortfall — fall
         back to one plain round, so composition with admission,
-        chunked prefill and preemption needs no special cases.
+        chunked prefill and preemption needs no special cases.  Returns
+        the requests it finished and its window (K + 1 positions).
         """
         K = self.draft_k
         for seq in self.sched.active:
@@ -1676,45 +1771,54 @@ class LPUEngine:
             return (self._fused_decode_round()
                     if self.sampling == "fused"
                     else self._host_decode_step())
-        for seq in self.sched.active:
-            if seq is not None and not seq.prefilling:
-                self._ensure_writable(seq, seq.pos, seq.pos + K + 1)
-        self._refresh_tables()
-        (last, pos, _, alive), (temps, top_ks, top_ps, _) = \
-            self._slot_state()
         B, K1 = self.slots, K + 1
-        toks = np.zeros((B, K1), np.int32)
-        draft = np.zeros((B, K), np.int32)
-        real = np.zeros((B,), np.int32)
-        toks[:, 0] = last
-        for slot, p in props.items():
-            draft[slot, :len(p)] = p
-            real[slot] = len(p)
-        toks[:, 1:] = draft
-        positions = pos[:, None] + np.arange(K1, dtype=np.int32)[None]
-        positions = np.where(alive[:, None], positions, 0) \
-            .astype(np.int32)
-        lens = np.where(alive[:, None], positions + 1, 1) \
-            .reshape(-1).astype(np.int32)
-        tables = np.repeat(self.block_tables, K1, axis=0)
-        flat_t = jnp.asarray(toks.reshape(1, B * K1))
-        flat_p = jnp.asarray(positions.reshape(1, B * K1))
+        with self.spans.span("lpu.prepare"):
+            for seq in self.sched.active:
+                if seq is not None and not seq.prefilling:
+                    self._ensure_writable(seq, seq.pos, seq.pos + K + 1)
+            self._refresh_tables()
+            (last, pos, _, alive), (temps, top_ks, top_ps, _) = \
+                self._slot_state()
+            toks = np.zeros((B, K1), np.int32)
+            draft = np.zeros((B, K), np.int32)
+            real = np.zeros((B,), np.int32)
+            toks[:, 0] = last
+            for slot, p in props.items():
+                draft[slot, :len(p)] = p
+                real[slot] = len(p)
+            toks[:, 1:] = draft
+            positions = pos[:, None] + np.arange(K1, dtype=np.int32)[None]
+            positions = np.where(alive[:, None], positions, 0) \
+                .astype(np.int32)
+            lens = np.where(alive[:, None], positions + 1, 1) \
+                .reshape(-1).astype(np.int32)
+            tables = np.repeat(self.block_tables, K1, axis=0)
         if self.sampling == "fused":
-            out, n_acc, self.cache, self.rng = self._verify(K)(
-                self.params, self.cache, flat_t, flat_p,
-                jnp.asarray(tables), jnp.asarray(lens),
-                jnp.asarray(draft), self.rng, jnp.asarray(temps),
-                jnp.asarray(top_ks), jnp.asarray(top_ps),
-                jnp.asarray(alive))
-            out = np.asarray(out)
-            n_acc = np.asarray(n_acc)
+            with self.spans.span("lpu.dispatch", window=K1):
+                out, n_acc, self.cache, self.rng = self._verify(K)(
+                    self.params, self.cache,
+                    jnp.asarray(toks.reshape(1, B * K1)),
+                    jnp.asarray(positions.reshape(1, B * K1)),
+                    jnp.asarray(tables), jnp.asarray(lens),
+                    jnp.asarray(draft), self.rng, jnp.asarray(temps),
+                    jnp.asarray(top_ks), jnp.asarray(top_ps),
+                    jnp.asarray(alive))
+            with self.spans.span("lpu.wait") as sp:
+                out = np.asarray(out)
+                n_acc = np.asarray(n_acc)
+                sp.count(bytes=out.nbytes + n_acc.nbytes)
             self.stats.host_syncs += 1
             self.stats.bytes_to_host += out.nbytes + n_acc.nbytes
         else:
-            rows, self.cache = self._verify(K)(
-                self.params, self.cache, flat_t, flat_p,
-                jnp.asarray(tables), jnp.asarray(lens))
-            rows_np = np.asarray(rows).reshape(B, K1, -1)
+            with self.spans.span("lpu.dispatch", window=K1):
+                rows, self.cache = self._verify(K)(
+                    self.params, self.cache,
+                    jnp.asarray(toks.reshape(1, B * K1)),
+                    jnp.asarray(positions.reshape(1, B * K1)),
+                    jnp.asarray(tables), jnp.asarray(lens))
+            with self.spans.span("lpu.wait") as sp:
+                rows_np = np.asarray(rows).reshape(B, K1, -1)
+                sp.count(bytes=rows_np.nbytes)
             self.stats.host_syncs += 1
             self.stats.bytes_to_host += rows_np.nbytes
             stoch = alive & (temps > 0.0)
@@ -1736,27 +1840,30 @@ class LPUEngine:
         self.stats.steps += 1
         self.stats.spec_rounds += 1
         self.stats.slot_steps += self.slots
-        for slot, seq in enumerate(self.sched.active):
-            if seq is None or seq.prefilling:
-                continue
-            req = seq.req
-            self.stats.busy_slot_steps += 1
-            n = int(n_acc[slot])
-            self.stats.draft_tokens += int(real[slot])
-            self.stats.accepted_tokens += min(n, int(real[slot]))
-            emit = [int(t) for t in out[slot, :n + 1]]
-            for j, tok in enumerate(emit):
-                self.stats.tokens += 1
-                req.out.append(tok)
-                seq.pos += 1
-                seq.last_token = tok
-                if req.stream_cb:
-                    req.stream_cb(req.rid, tok)
-                if self._should_finish(seq, tok):
-                    self.stats.overrun_tokens += len(emit) - j - 1
-                    finished.append(self._finish(seq))
-                    break
-        return finished
+        with self.spans.span("lpu.reconcile") as sp:
+            tokens = self.stats.tokens
+            for slot, seq in enumerate(self.sched.active):
+                if seq is None or seq.prefilling:
+                    continue
+                req = seq.req
+                self.stats.busy_slot_steps += 1
+                n = int(n_acc[slot])
+                self.stats.draft_tokens += int(real[slot])
+                self.stats.accepted_tokens += min(n, int(real[slot]))
+                emit = [int(t) for t in out[slot, :n + 1]]
+                for j, tok in enumerate(emit):
+                    self.stats.tokens += 1
+                    req.out.append(tok)
+                    seq.pos += 1
+                    seq.last_token = tok
+                    if req.stream_cb:
+                        req.stream_cb(req.rid, tok)
+                    if self._should_finish(seq, tok):
+                        self.stats.overrun_tokens += len(emit) - j - 1
+                        finished.append(self._finish(seq))
+                        break
+            sp.count(tokens=self.stats.tokens - tokens)
+        return finished, K1
 
     def drain(self) -> Dict[int, List[int]]:
         """Step until the queue and all slots are empty; returns

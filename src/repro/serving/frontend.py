@@ -23,7 +23,10 @@ asyncio front end that
 * emits telemetry — an optional :class:`repro.serving.tracker.Tracker`
   receives per-window ``EngineStats`` deltas (snapshot-and-diff via
   :class:`EngineTap`) and a per-request TTFT / ms-per-token record at
-  each stream's end.
+  each stream's end; with a :class:`repro.serving.tracker.SpanRecorder`
+  attached to the engine, the pump's ticks and submits are spans too,
+  and every recorded span goes to the tracker when the front end
+  stops.
 
 Concurrency model: ONE event loop, no threads.  The pump task calls the
 engine's synchronous ``step()`` directly and yields
@@ -150,8 +153,12 @@ class AsyncFrontend:
         self.budget = budget
         self.tracker = tracker if tracker is not None else NullTracker()
         self.clock = clock
+        # per-tick EngineStats deltas feed the tracker and the budget
+        # scheduler; with neither there is nothing to diff for
+        watched = budget is not None or \
+            not isinstance(self.tracker, NullTracker)
         self._taps = [EngineTap(e, ring=i)
-                      for i, e in enumerate(self.engines)]
+                      for i, e in enumerate(self.engines)] if watched else []
         self._streams: Dict[int, TokenStream] = {}
         self._inflight: Dict[int, TokenStream] = {}
         self.counters = dict(submitted=0, completed=0, failed=0,
@@ -176,13 +183,22 @@ class AsyncFrontend:
             self._closing = False
             self._task = asyncio.create_task(self._pump())
 
+    @property
+    def spans(self):
+        """The engine's span recorder (``NO_SPANS`` unless attached)."""
+        return self.engines[0].spans
+
     async def stop(self) -> None:
-        """Finish in-flight work, stop the pump, flush the tracker."""
+        """Finish in-flight work, stop the pump, write the recorded
+        spans and flush the tracker."""
         self._closing = True
         self._wake.set()
         if self._task is not None:
             await self._task
             self._task = None
+        if self.spans.on:
+            for rec in self.spans.records():
+                self.tracker.log(rec)
         self.tracker.finish()
 
     async def join(self) -> None:
@@ -207,8 +223,10 @@ class AsyncFrontend:
                               "limit": self.max_pending})
             raise AdmissionRejected(len(self._inflight), self.max_pending)
         t0 = self.clock()
-        rid = self.engine.submit(list(prompt), max_new_tokens, params,
-                                 stream_cb=self._on_token)
+        with self.spans.span("lpu.submit") as sp:
+            rid = self.engine.submit(list(prompt), max_new_tokens, params,
+                                     stream_cb=self._on_token)
+            sp.count(rid=rid)
         stream = TokenStream(rid, self, RequestTimeline(rid, t0,
                                                         tenant=tenant))
         self._streams[rid] = stream
@@ -248,7 +266,9 @@ class AsyncFrontend:
             return
         self.counters[status] += 1
         stream._finish(status, error)
-        self.tracker.log(stream.timeline.record(status, self.clock()))
+        t = self.clock()
+        self.spans.request_done(rid, stream.timeline.t_first, t)
+        self.tracker.log(stream.timeline.record(status, t))
         if not self._inflight:
             self._idle.set()
 
@@ -291,26 +311,30 @@ class AsyncFrontend:
 
     def _tick(self) -> None:
         """One engine step with SLO planning + telemetry around it."""
-        if self.budget is not None:
-            self._apply_budget()
-        t0 = self.clock()
-        done = self.engine.step()
-        dt = self.clock() - t0
-        deltas = []
-        for tap in self._taps:
-            before = dict(tap._prev)
-            rec = tap.emit(self.tracker, self.clock(), dt_ms=dt * 1e3)
-            deltas.append(rec["delta"] if rec is not None else
-                          {k: 0 for k in before})
-        if self.budget is not None:
-            self._observe_budget(dt, deltas)
-        for req in done:
-            if req.rid not in self._inflight:
-                continue
-            if req.failed:
-                self._finalize(req.rid, "failed", req.error)
-            else:
-                self._finalize(req.rid, "completed")
+        with self.spans.span("lpu.tick") as sp:
+            if self.budget is not None:
+                self._apply_budget()
+            t0 = self.clock()
+            done = self.engine.step()
+            dt = self.clock() - t0
+            deltas = []
+            for tap in self._taps:
+                before = dict(tap._prev)
+                rec = tap.emit(self.tracker, self.clock(), dt_ms=dt * 1e3)
+                deltas.append(rec["delta"] if rec is not None else
+                              {k: 0 for k in before})
+            if self.budget is not None:
+                self._observe_budget(dt, deltas)
+            finished = 0
+            for req in done:
+                if req.rid not in self._inflight:
+                    continue
+                finished += 1
+                if req.failed:
+                    self._finalize(req.rid, "failed", req.error)
+                else:
+                    self._finalize(req.rid, "completed")
+            sp.count(finished=finished)
 
     async def _pump(self) -> None:
         while True:

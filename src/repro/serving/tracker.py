@@ -5,12 +5,13 @@ scheduler, the fleet supervisor — emits structured time-series records
 through ONE seam instead of printing banners.  The protocol is
 levanter-style: a tracker is anything with ``log(record)`` / ``finish()``;
 implementations here are deliberately boring (jsonl file, in-memory ring
-buffer, composite fan-out) so tests and CI can consume the stream
-without a metrics backend.
+buffer) so tests and CI can consume the stream without a metrics
+backend.
 
 Record schema (``validate_record``): every record carries
 
-* ``kind``  — ``"engine_window"`` | ``"request"`` | ``"event"``
+* ``kind``  — ``"engine_window"`` | ``"request"`` | ``"event"`` |
+  ``"span"``
 * ``t``     — seconds, caller-supplied monotonic clock
 
 plus per-kind required fields:
@@ -21,6 +22,10 @@ plus per-kind required fields:
 * ``request`` — ``rid``, ``status`` (completed|failed|cancelled|
   rejected), ``tokens``, ``ttft_ms``, ``ms_per_token``
 * ``event`` — ``name`` plus free-form detail
+* ``span`` — ``id``, ``name``, ``end`` (>= ``t``, its start),
+  ``parent`` (the enclosing span's ``id``, -1 at the top) and
+  ``counts`` (numbers counted where the span's work happened), plus
+  ``rid`` on a request's spans (see :class:`SpanRecorder`)
 
 EngineStats delta accounting
 ----------------------------
@@ -36,22 +41,26 @@ a migration in the middle).
 """
 from __future__ import annotations
 
+import gc
 import json
+import threading
+import time
 from collections import deque
 from dataclasses import fields as dc_fields
-from typing import Any, Deque, Dict, Iterable, List, Optional
+from typing import Any, Callable, Deque, Dict, List, Optional
 
 # EngineStats fields excluded from delta accounting: gauges (high-water
 # marks are not flows) and wall (float accumulation, tracked as dt_ms on
 # the window record itself).
 GAUGE_FIELDS = frozenset({"peak_pool_blocks", "wall"})
 
-KINDS = ("engine_window", "request", "event")
+KINDS = ("engine_window", "request", "event", "span")
 REQUEST_STATUSES = ("completed", "failed", "cancelled", "rejected")
 _REQUIRED = {
     "engine_window": ("ring", "step", "dt_ms", "delta"),
     "request": ("rid", "status", "tokens", "ttft_ms", "ms_per_token"),
     "event": ("name",),
+    "span": ("id", "name", "end", "parent", "counts"),
 }
 
 
@@ -87,6 +96,12 @@ def validate_record(rec: Dict[str, Any]) -> None:
                              f"{REQUEST_STATUSES}")
         if rec["tokens"] < 0:
             raise ValueError(f"tokens={rec['tokens']} must be >= 0")
+    elif kind == "span":
+        if not rec["end"] >= t:
+            raise ValueError(f"span {rec['name']!r} ends at {rec['end']!r}, "
+                             f"before its start {t}")
+        if not isinstance(rec["counts"], dict):
+            raise ValueError("span counts must be a dict")
 
 
 class Tracker:
@@ -177,22 +192,6 @@ class RingBufferTracker(Tracker):
             raise ValueError(f"n={n} must be >= 0")
         buf = list(self._buf)
         return buf[len(buf) - min(n, len(buf)):]
-
-
-class CompositeTracker(Tracker):
-    """Fan one stream out to several sinks (jsonl artifact + in-memory
-    window is the usual pair)."""
-
-    def __init__(self, trackers: Iterable[Tracker]):
-        self.trackers = list(trackers)
-
-    def log(self, rec: Dict[str, Any]) -> None:
-        for tr in self.trackers:
-            tr.log(rec)
-
-    def finish(self) -> None:
-        for tr in self.trackers:
-            tr.finish()
 
 
 # ---------------------------------------------------------------------------
@@ -291,3 +290,221 @@ class RequestTimeline:
         if self.tenant is not None:
             rec["tenant"] = self.tenant
         return rec
+
+
+# ---------------------------------------------------------------------------
+# Spans: where the host's time goes inside the serving program
+# ---------------------------------------------------------------------------
+
+class _NoSpan:
+    """The span an unrecorded engine opens: does nothing at all."""
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+    def count(self, **counts) -> None:
+        pass
+
+
+NO_SPAN = _NoSpan()
+
+
+class NoSpans:
+    """The span recorder of an engine nobody records: every span is the
+    shared :data:`NO_SPAN`, so a span costs one method call — no clock
+    read, no profiler annotation, nothing kept."""
+    on = False
+
+    def span(self, name: str, **counts) -> _NoSpan:
+        return NO_SPAN
+
+    def queued(self, rid: int) -> None:
+        pass
+
+    def admitted(self, rid: int) -> None:
+        pass
+
+    def request_done(self, rid: int, t_first: Optional[float],
+                     t_end: float) -> None:
+        pass
+
+
+NO_SPANS = NoSpans()
+
+
+class Span:
+    """One span: ``name`` from ``start`` to ``end`` on the recorder's
+    clock, inside span ``parent`` (None at the top), with the ``counts``
+    of the work done in it.  Use as a context manager; :meth:`count`
+    adds counts known only once the work is done."""
+    __slots__ = ("rec", "name", "start", "end", "parent", "rid", "counts",
+                 "_ann")
+
+    def __init__(self, rec: "SpanRecorder", name: str,
+                 rid: Optional[int] = None, counts: Optional[dict] = None):
+        self.rec = rec
+        self.name = name
+        self.rid = rid
+        self.counts = counts or {}
+        self.start = self.end = float("nan")
+        self.parent: Optional[Span] = None
+        self._ann = None
+
+    def __enter__(self) -> "Span":
+        rec = self.rec
+        self._begin(rec._open[-1] if rec._open else None)
+        rec._open.append(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._end(*exc)
+        self.rec._open.pop()
+
+    def _begin(self, parent: Optional["Span"]) -> None:
+        self.parent = parent
+        self.rec.spans.append(self)
+        self._ann = self.rec._annotation(self.name, **self.counts)
+        self._ann.__enter__()
+        self.start = self.rec.clock()
+
+    def _end(self, *exc) -> None:
+        self.end = self.rec.clock()
+        self._ann.__exit__(*exc)
+        self._ann = None
+
+    def count(self, **counts) -> None:
+        self.counts.update(counts)
+        if self._ann is not None:
+            self._ann.set_metadata(**counts)
+
+    def record(self, idx: int, parent: int) -> Dict[str, Any]:
+        rec = {"kind": "span", "t": self.start, "id": idx,
+               "name": self.name, "end": self.end, "parent": parent,
+               "counts": dict(self.counts)}
+        if self.rid is not None:
+            rec["rid"] = self.rid
+        return rec
+
+
+class SpanRecorder(NoSpans):
+    """Spans and counts at the serving program's layer boundaries.
+
+    Attach it to an engine or a fleet (:meth:`attach`); the engine, its
+    front end and the garbage collector then open named spans (listed
+    in ``docs/serving.md``, "Spans"):
+
+    * each span opens ``jax.profiler.TraceAnnotation(name, **counts)``,
+      so that while a profiler trace is collected the span lands in it
+      on the device trace's clock, its counts as event stats;
+    * each is also kept in memory (:attr:`spans`), with its parent,
+      until :meth:`records` hands them out as ``span`` records — the
+      front end writes them through its :class:`Tracker` when it stops.
+
+    A request's own spans are kept in memory only, under its rid
+    (:attr:`requests`): ``lpu.request.queue`` from submit to admission
+    (a preempted request queued again opens another),
+    ``lpu.request.prefill`` from admission to its first token and
+    ``lpu.request.decode`` from there to its end.  The first-token and
+    end stamps are the front end's, so its clock must be this one.
+
+    Nothing is dropped while attached: a long-running server detaches
+    (or reads :meth:`records` and builds a new recorder) to bound it.
+    """
+    on = True
+
+    def __init__(self, clock: Callable[[], float] = time.perf_counter):
+        from jax.profiler import TraceAnnotation
+        self.clock = clock
+        self._annotation = TraceAnnotation
+        self.spans: List[Span] = []
+        self.requests: Dict[int, List[Span]] = {}
+        self._open: List[Span] = []
+        self._queued: Dict[int, float] = {}
+        self._admitted: Dict[int, float] = {}
+        self._engines: List[Any] = []
+        self._gc: Optional[Span] = None
+
+    # -- wiring ----------------------------------------------------------
+
+    def attach(self, engine) -> "SpanRecorder":
+        """Record ``engine`` (an ``LPUEngine`` or every ring of a
+        ``MultiRingEngine``) and the garbage collector's pauses."""
+        for eng in getattr(engine, "engines", [engine]):
+            eng.spans = self
+            self._engines.append(eng)
+        self._thread = threading.get_ident()
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+        return self
+
+    def detach(self) -> None:
+        """Stop recording: the engines go back to :data:`NO_SPANS`."""
+        for eng in self._engines:
+            eng.spans = NO_SPANS
+        self._engines = []
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        # a collection holds the interpreter, whichever thread runs it;
+        # it nests in the serving thread's open span only when that
+        # thread runs it, and never joins the span stack
+        if phase == "start":
+            self._gc = Span(self, "py.gc",
+                            counts={"generation": info["generation"]})
+            mine = threading.get_ident() == self._thread
+            self._gc._begin(self._open[-1] if mine and self._open else None)
+        elif self._gc is not None:
+            self._gc.count(collected=info["collected"])
+            self._gc._end(None, None, None)
+            self._gc = None
+
+    # -- spans -----------------------------------------------------------
+
+    def span(self, name: str, **counts) -> Span:
+        return Span(self, name, counts=counts)
+
+    def _request_span(self, name: str, rid: int, start: float,
+                      end: float) -> None:
+        sp = Span(self, name, rid)
+        sp.start, sp.end = start, end
+        self.spans.append(sp)
+        self.requests.setdefault(rid, []).append(sp)
+
+    def queued(self, rid: int) -> None:
+        """Request ``rid`` entered the engine's queue (submit, or a
+        preemption sent it back)."""
+        self._queued[rid] = self.clock()
+
+    def admitted(self, rid: int) -> None:
+        """Request ``rid`` left the queue for a slot."""
+        t = self.clock()
+        t0 = self._queued.pop(rid, None)
+        if t0 is not None:
+            self._request_span("lpu.request.queue", rid, t0, t)
+        self._admitted.setdefault(rid, t)
+
+    def request_done(self, rid: int, t_first: Optional[float],
+                     t_end: float) -> None:
+        """Request ``rid`` ended at ``t_end``, its first token at
+        ``t_first`` (None: it had none), both on the front end's
+        clock."""
+        t_adm = self._admitted.pop(rid, None)
+        self._queued.pop(rid, None)
+        if t_first is None:
+            return
+        if t_adm is not None:
+            self._request_span("lpu.request.prefill", rid, t_adm, t_first)
+        self._request_span("lpu.request.decode", rid, t_first, t_end)
+
+    def records(self) -> List[Dict[str, Any]]:
+        """Every closed span as a ``span`` record, in opening order; a
+        record's ``id`` is its span's index in :attr:`spans`, its
+        ``parent`` the enclosing span's (-1 at the top)."""
+        ids = {id(sp): i for i, sp in enumerate(self.spans)}
+        return [sp.record(i, ids[id(sp.parent)] if sp.parent else -1)
+                for i, sp in enumerate(self.spans) if sp.end == sp.end]

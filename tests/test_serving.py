@@ -231,6 +231,10 @@ def test_ring_sharded_paged_engine_matches_dense_tp1():
     # dense ring cache too (the contiguous fast path under tp)
     engd = LPUEngine(m2, p2, slots=3, max_seq=64, paged=False, mesh=mesh)
     assert engd.generate(prompts, max_new_tokens=10) == ref
+    # the ring programs carry the engine's stable names
+    for e in (eng, engd):
+        assert e.lower_decode_text().startswith(
+            'module @jit_lpu_decode_window '), e.lower_decode_text()[:80]
     print('PASS')
     """, n_devices=2)
     assert "PASS" in out
