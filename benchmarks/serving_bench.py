@@ -100,6 +100,7 @@ import numpy as np  # noqa: E402
 
 from repro.compiler.mapper import plan_model  # noqa: E402
 from repro.configs import get_config  # noqa: E402
+from repro.core.hlo import pool_census  # noqa: E402
 from repro.launch.mesh import make_serving_mesh  # noqa: E402
 from repro.models.registry import build_model  # noqa: E402
 from repro.serving.config import EngineConfig  # noqa: E402
@@ -191,6 +192,16 @@ def view_tensor_count(eng) -> int:
     dt = MLIR_DTYPE[jnp.dtype(eng.kv_dtype).name]
     sig = f"tensor<{eng.slots}x{eng.max_seq}x{a.gp}x{a.d_head}x{dt}>"
     return txt.count(sig)
+
+
+def pool_layer_ops(eng) -> int:
+    """MEASURED in-place check: ops of the compiled decode program that
+    cut one layer's KV pool out of the stacked pool or relay one out
+    (:func:`repro.core.hlo.pool_census`).  The streamed kernel reads the
+    stack in place through its layer index, so its program must hold
+    none; a regression to per-layer slicing shows up here."""
+    text = eng.lower_decode().compile().as_text()
+    return len(pool_census(text, eng.cache["l0"]["k"]).per_layer)
 
 
 def ring_rows(cfg, prompts, dense_outs, args):
@@ -577,6 +588,7 @@ REQUIRED_ROW_KEYS = {"mode", "tokens_per_s", "ms_per_token", "occupancy",
                      "decode_steps", "prefills", "prefill_traces",
                      "preemptions", "kv_bytes", "kv_dense_equiv_bytes",
                      "kv_moved_bytes_per_step", "view_tensors_in_program",
+                     "pool_layer_ops_in_program",
                      "sampling", "steps_per_sync", "host_syncs",
                      "prefill_syncs", "syncs_per_token",
                      "bytes_to_host_per_token", "overrun_tokens",
@@ -905,6 +917,8 @@ def main():
             # measured from the lowered program, not the formula
             "view_tensors_in_program": (view_tensor_count(eng)
                                         if eng.paged else None),
+            "pool_layer_ops_in_program": (pool_layer_ops(eng)
+                                          if eng.paged else None),
             "sampling": eng.sampling,
             "steps_per_sync": eng.steps_per_sync,
             "host_syncs": st.host_syncs,
@@ -1048,6 +1062,8 @@ def main():
     assert by_mode["paged-gather"]["view_tensors_in_program"] > 0, \
         "gather oracle no longer materializes the view (shape drift? " \
         "update view_tensor_count)"
+    assert by_mode["paged-stream"]["pool_layer_ops_in_program"] == 0, \
+        "streamed decode program slices or relays out a layer's pool"
     # host-sync gates (paper C1): fused sampling must NOT ship the
     # logits row — device->host payload per token is a small O(slots)
     # constant (int32 ids + window slack), never O(vocab); the synced
@@ -1164,6 +1180,8 @@ def main():
              "precision row's pool (data + scales) exceeded its budget")
         assert r["view_tensors_in_program"] == 0, \
             (r["mode"], "precision row regressed to a gathered KV view")
+        assert r["pool_layer_ops_in_program"] == 0, \
+            (r["mode"], "precision row slices a layer's pool per step")
     assert kq["greedy_prefix_agreement"] >= KV_INT8_DRIFT_BOUND, \
         (kq["greedy_prefix_agreement"],
          f"int8 greedy drift exceeded the {KV_INT8_DRIFT_BOUND} "

@@ -11,6 +11,8 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+import numpy as np
+
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
     "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8, "c64": 8,
@@ -110,6 +112,106 @@ def op_census(hlo_text: str, ops=("fusion", "dot", "convolution",
     out = {}
     for op in ops:
         out[op] = len(re.findall(rf"\s{op}(?:\.\d+)?\(", hlo_text))
+    return out
+
+
+_INSTR_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%(\S+)\s*=\s*([a-z0-9]+)\[([0-9,]*)\]"
+    r"(?:\{([^}]*)\})?\s+([a-z][a-z0-9-]*)\(")
+_COMP_RE = re.compile(r"^\s*(?:ENTRY\s+)?%(\S+)\s*\(.*\{\s*$")
+_ROOT_OP_RE = re.compile(r"^\s*ROOT\s+%\S+\s*=\s*\S+\s+([a-z][a-z0-9-]*)\(")
+# ops that name, forward or reinterpret a buffer without writing it
+_NO_WRITE = ("parameter", "get-tuple-element", "bitcast", "tuple", "while",
+             "call", "conditional", "custom-call", "copy-start",
+             "opt-barrier")
+# in-place updates: their result aliases their first operand
+_IN_PLACE = ("scatter", "dynamic-update-slice")
+
+
+@dataclass
+class PoolCensus:
+    """What a compiled program does to a stacked KV pool (see
+    :func:`pool_census`): op names, and the pool-shaped layouts."""
+    copies: List[str] = field(default_factory=list)
+    per_layer: List[str] = field(default_factory=list)
+    layouts: List[str] = field(default_factory=list)
+
+    def row(self) -> Dict[str, object]:
+        return {"whole_pool_copies": len(self.copies),
+                "per_layer_pool_ops": len(self.per_layer),
+                "pool_layouts": sorted(set(self.layouts))}
+
+
+def hlo_dtype(dtype) -> str:
+    """HLO's name of a numpy / JAX dtype: ``bf16``, ``f32``, ``s8``,
+    ``f8e4m3fn``, ``pred``, ..."""
+    name = np.dtype(dtype).name
+    if name == "bool":
+        return "pred"
+    if name.startswith("float8_"):
+        return "f8" + name[len("float8_"):]
+    for prefix, short in (("bfloat", "bf"), ("float", "f"), ("uint", "u"),
+                          ("int", "s")):
+        if name.startswith(prefix):
+            return short + name[len(prefix):]
+    raise ValueError(f"no HLO name for dtype {name}")
+
+
+def pool_census(hlo_text: str, leaf) -> PoolCensus:
+    """Count the ops of compiled HLO text that move a stacked pool leaf.
+
+    ``leaf`` is the leaf, or anything with its (L, ...) ``shape`` and its
+    ``dtype``.  Only ops that run as their own kernel are counted:
+    instructions inside fused computations are parts of their fusion.
+
+    * ``copies``: ops writing a value of the whole leaf's size (its dims
+      in any order, so a transposed relayout counts) that is neither an
+      in-place update (scatter, dynamic-update-slice, or a fusion rooted
+      in one) nor a view (bitcast, tuple plumbing, a custom call).
+    * ``per_layer``: ops producing one layer of it, ``pool_shape[1:]``
+      with or without a leading 1, in any order: a slice cut from the
+      stack or a relayout of one.
+    * ``layouts``: the minor-to-major layout of every value of exactly
+      ``pool_shape``, one entry per value.
+    """
+    pool_shape = [int(d) for d in leaf.shape]
+    dtype = hlo_dtype(leaf.dtype)
+    full, layer = sorted(pool_shape), sorted(pool_shape[1:])
+    layer1 = sorted([1] + layer)
+    fused, roots, comp = set(), {}, None
+    lines = hlo_text.splitlines()
+    for line in lines:
+        m = _COMP_RE.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = _ROOT_OP_RE.match(line)
+        if m and comp is not None:
+            roots[comp] = m.group(1)
+        fused.update(re.findall(r"calls=%([^\s,)]+)", line))
+    out, comp = PoolCensus(), None
+    for line in lines:
+        m = _COMP_RE.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = _INSTR_RE.match(line)
+        if not m or comp in fused or m.group(2) != dtype:
+            continue
+        name, _, dims, layout, op = m.groups()
+        shape = [int(d) for d in dims.split(",") if d]
+        dims = sorted(shape)
+        if dims == full:
+            if shape == pool_shape:
+                out.layouts.append((layout or "").split(":")[0])
+            callee = re.search(r"calls=%([^\s,)]+)", line)
+            in_place = op in _IN_PLACE or (
+                op == "fusion" and callee is not None
+                and roots.get(callee.group(1)) in _IN_PLACE)
+            if op not in _NO_WRITE and not in_place:
+                out.copies.append(name)
+        elif dims in (layer, layer1) and op not in _NO_WRITE:
+            out.per_layer.append(name)
     return out
 
 

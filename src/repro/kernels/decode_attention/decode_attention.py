@@ -26,6 +26,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import interpret_mode
 
+LANE = 128
+
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, s_tiles: int, block_s: int,
@@ -73,9 +75,9 @@ def _head_row(scale_ref, g) -> jax.Array:
     return jnp.sum(jnp.where(rows == g, sc, 0.0), axis=0, keepdims=True)
 
 
-def _paged_decode_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
-                         t_blocks: int, block_s: int, scale: float,
-                         quantized: bool = False):
+def _paged_decode_kernel(*refs, n_prefetch: int, t_blocks: int,
+                         block_s: int, scale: float,
+                         quantized: bool = False, planes_t: bool = False):
     """Paged variant: same online-softmax stream as ``_decode_kernel`` but
     KV tiles are fetched through the block table (scalar-prefetched, so the
     DMA address is known before the body runs — the LPU's address-generator
@@ -94,8 +96,14 @@ def _paged_decode_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
     ``dh``, so it multiplies the score column (K) and the probability
     column (V) instead of the tile — no (bs, 1) relayout.  The folded new
     token's K/V stays full precision (it is a fresh activation, not pool
-    storage)."""
-    rest = list(rest)
+    storage).
+
+    ``n_prefetch`` scalar-prefetch refs lead: (lengths, tables), or
+    (layer, lengths, tables) for a stacked pool.  ``planes_t``: the K/V
+    tiles arrive as (dh, bs) planes (bs on the lanes) instead of
+    (bs, dh); the two dots contract over the matching axis."""
+    len_ref, tbl_ref = refs[n_prefetch - 2:n_prefetch]
+    q_ref, k_ref, v_ref, *rest = refs[n_prefetch:]
     if quantized:
         ks_ref, vs_ref = rest[0], rest[1]
         rest = rest[2:]
@@ -115,9 +123,10 @@ def _paged_decode_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0].astype(jnp.float32) * scale            # (gs, dh)
-    k = k_ref[...].astype(jnp.float32)                  # (block_s, dh)
+    k = k_ref[...].astype(jnp.float32)          # (block_s, dh) | (dh, bs)
     v = v_ref[...].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+    kv_axis = 0 if planes_t else 1              # k's dh axis
+    s = jax.lax.dot_general(q, k, (((1,), (kv_axis,)), ((), ())),
                             preferred_element_type=jnp.float32)
     if quantized:
         s = s * _head_row(ks_ref, g)
@@ -132,7 +141,7 @@ def _paged_decode_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, -1, keepdims=True)
     if quantized:
         p = p * _head_row(vs_ref, g)
-    pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+    pv = jax.lax.dot_general(p, v, (((1,), (1 - kv_axis,)), ((), ())),
                              preferred_element_type=jnp.float32)
     acc_ref[...] = acc_ref[...] * corr + pv
     m_ref[...] = m_new
@@ -156,10 +165,22 @@ def _paged_decode_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
                     jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
+def lane_dense_planes(bs: int, dh: int) -> bool:
+    """Whether a (bs, dh) pool plane is stored with ``bs`` on the lanes.
+
+    The TPU lays a row-major (..., bs, dh) array out with its last two
+    dims swapped when ``dh`` would leave lanes empty and ``bs`` fills
+    them (d_head 64 at block 128): the pool then lives as (dh, bs)
+    planes.  The stacked-pool kernel reads those planes as they lie, so
+    nothing converts the pool to the row-major tile."""
+    return dh % LANE != 0 and bs % LANE == 0
+
+
 def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
                                   v_pages: jax.Array,
                                   block_tables: jax.Array,
                                   lengths: jax.Array, *,
+                                  layer: jax.Array = None,
                                   k_new: jax.Array = None,
                                   v_new: jax.Array = None,
                                   k_scale: jax.Array = None,
@@ -177,52 +198,81 @@ def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
     block's last two dims be (8, 128)-aligned or span the array holds for
     any ``bs`` and ``d_head`` (64-wide heads included).
 
+    Stacked pool (dispatch by rank): k_pages,v_pages (L,N,G,bs,dh) hold
+    every layer, and ``layer`` (a traced int32 scalar) picks one.  The
+    layer index rides scalar prefetch beside the table, so the KV index
+    map is (layer, tbl[b, t], g, 0, 0) and the layer's slice is never
+    cut out of the pool: the decode scan hands its carry straight in.
+    Where :func:`lane_dense_planes` holds, the kernel reads the (dh, bs)
+    planes the pool is stored as (a transposed view XLA lays over the
+    same bytes).  A 4-D pool keeps the (bs, dh) per-layer call.
+
     ``k_new/v_new`` ((B,G,dh), both or neither): the current token's K/V,
     folded into the softmax carry *after* the streamed pool tiles — used
     by the decode path that reads the cache pre-update and lets the
     caller scatter the new row into the pool afterwards.
 
-    ``k_scale/v_scale`` ((N,G,bs), both or neither): the quantized
-    pool's absmax scale side-arrays; the whole (G, bs) scale plane of a
-    block rides the SAME block-table indirection as the value tiles and
-    dequantization runs inside the tile loop."""
+    ``k_scale/v_scale`` ((N,G,bs), stacked (L,N,G,bs); both or neither):
+    the quantized pool's absmax scale side-arrays; the whole (G, bs)
+    scale plane of a block rides the SAME block-table indirection as the
+    value tiles and dequantization runs inside the tile loop."""
     B, H, dh = q.shape
-    N, G, bs, _ = k_pages.shape
+    stacked = k_pages.ndim == 5
+    assert stacked == (layer is not None), (k_pages.shape, layer)
+    N, G, bs, _ = k_pages.shape[-4:]
     T = block_tables.shape[1]
     assert H % G == 0, (H, G)
     assert (k_new is None) == (v_new is None)
     assert (k_scale is None) == (v_scale is None)
     gs = H // G
     qg = q.reshape(B * G, gs, dh)
+    planes_t = stacked and lane_dense_planes(bs, dh)
+    prefetch = [lengths, block_tables]
+    if stacked:
+        prefetch.insert(0, jnp.reshape(layer, (1,)).astype(jnp.int32))
 
-    kernel = functools.partial(_paged_decode_kernel, t_blocks=T, block_s=bs,
-                               scale=1.0 / math.sqrt(dh),
-                               quantized=k_scale is not None)
-    kv_spec = pl.BlockSpec((None, None, bs, dh),
-                           lambda b, g, t, lens, tbl: (tbl[b, t], g, 0, 0))
+    def lead(pf):
+        """Leading block index of a pool operand: the stacked pool's
+        layer (scalar-prefetch ref 0), none for a 4-D pool."""
+        return (pf[0][0],) if stacked else ()
+    n_lead = int(stacked)
+
+    kernel = functools.partial(_paged_decode_kernel,
+                               n_prefetch=len(prefetch), t_blocks=T,
+                               block_s=bs, scale=1.0 / math.sqrt(dh),
+                               quantized=k_scale is not None,
+                               planes_t=planes_t)
+    if planes_t:
+        k_pages = jnp.swapaxes(k_pages, -1, -2)
+        v_pages = jnp.swapaxes(v_pages, -1, -2)
+    plane = (dh, bs) if planes_t else (bs, dh)
+    kv_spec = pl.BlockSpec(
+        (None,) * (n_lead + 2) + plane,
+        lambda b, g, t, *pf: lead(pf) + (pf[-1][b, t], g, 0, 0))
     in_specs = [
         pl.BlockSpec((1, gs, dh),
-                     lambda b, g, t, lens, tbl: (b * G + g, 0, 0)),
+                     lambda b, g, t, *pf: (b * G + g, 0, 0)),
         kv_spec, kv_spec,
     ]
-    operands = [lengths, block_tables, qg, k_pages, v_pages]
+    operands = [qg, k_pages, v_pages]
     if k_scale is not None:
         scale_spec = pl.BlockSpec(
-            (None, G, bs), lambda b, g, t, lens, tbl: (tbl[b, t], 0, 0))
+            (None,) * (n_lead + 1) + (G, bs),
+            lambda b, g, t, *pf: lead(pf) + (pf[-1][b, t], 0, 0))
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
     if k_new is not None:
         new_spec = pl.BlockSpec((1, 1, dh),
-                                lambda b, g, t, lens, tbl: (b * G + g, 0, 0))
+                                lambda b, g, t, *pf: (b * G + g, 0, 0))
         in_specs += [new_spec, new_spec]
         operands += [k_new.reshape(B * G, 1, dh),
                      v_new.reshape(B * G, 1, dh)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=(B, G, T),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, gs, dh),
-                               lambda b, g, t, lens, tbl: (b * G + g, 0, 0)),
+                               lambda b, g, t, *pf: (b * G + g, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((gs, 1), jnp.float32),
             pltpu.VMEM((gs, 1), jnp.float32),
@@ -235,7 +285,7 @@ def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B * G, gs, dh), q.dtype),
         interpret=interpret_mode(interpret),
         name="paged_decode_attention",
-    )(*operands)
+    )(*prefetch, *operands)
     return out.reshape(B, H, dh)
 
 

@@ -15,11 +15,10 @@ import jax.numpy as jnp
 
 from repro.kernels import interpret_mode
 from repro.kernels.decode_attention.decode_attention import (
-    decode_attention_pallas, paged_decode_attention_pallas)
+    LANE, decode_attention_pallas, paged_decode_attention_pallas)
 from repro.kernels.decode_attention.ref import (decode_attention_ref,
                                                 paged_decode_attention_ref)
 
-LANE = 128
 VMEM_BUDGET = 32 * 2 ** 20
 
 
@@ -128,6 +127,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, block_tables: jax.Array,
                            lengths: jax.Array, *,
+                           layer: Optional[jax.Array] = None,
                            k_new: Optional[jax.Array] = None,
                            v_new: Optional[jax.Array] = None,
                            k_scale: Optional[jax.Array] = None,
@@ -142,25 +142,36 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     contiguous copy).  ``use_pallas=False`` asks for the gather oracle
     (:func:`paged_decode_attention_ref`) by name; nothing falls back to it.
 
+    A 5-D pool (L,N,G,bs,dh) is every layer's pool stacked, and
+    ``layer`` (int32 scalar, traced) is the one attended: the kernel
+    takes the layer index in scalar prefetch and reads the stack in
+    place, the oracle indexes it.  The rank of the pool picks the call;
+    a 4-D pool takes no ``layer``.
+
     ``k_new/v_new`` ((B,G,dh)): the current token's K/V, attended *in
     addition to* the ``lengths`` resident positions — the pool is read
     pre-update and the caller scatters the new row afterwards, so decode
     never rewrites (or copies) the pool to append one token.
 
-    ``k_scale/v_scale`` ((N,G,bs)): absmax scale side-arrays of a
-    quantized (int8/fp8) pool.  The kernel dequantizes inside its tile
-    loop; the oracle dequantizes the gathered view — both read the pool
-    at the quantized byte width.
+    ``k_scale/v_scale`` ((N,G,bs), stacked (L,N,G,bs)): absmax scale
+    side-arrays of a quantized (int8/fp8) pool.  The kernel dequantizes
+    inside its tile loop; the oracle dequantizes the gathered view —
+    both read the pool at the quantized byte width.
     """
+    if (k_pages.ndim == 5) != (layer is not None):
+        raise ValueError("paged_decode_attention: a stacked (L,N,G,bs,dh) "
+                         "pool takes a layer index, a (N,G,bs,dh) pool none "
+                         f"(pool {k_pages.shape}, layer={layer})")
     if not use_pallas:
         return paged_decode_attention_ref(
-            q, k_pages, v_pages, block_tables, lengths, k_new=k_new,
-            v_new=v_new, k_scale=k_scale, v_scale=v_scale)
-    H, G = q.shape[1], k_pages.shape[1]
+            q, k_pages, v_pages, block_tables, lengths, layer=layer,
+            k_new=k_new, v_new=v_new, k_scale=k_scale, v_scale=v_scale)
+    H, G = q.shape[1], k_pages.shape[-3]
     if H % G:
         raise ValueError(f"paged_decode_attention: {H} q heads do not "
                          f"group over {G} kv heads")
     return paged_decode_attention_pallas(q, k_pages, v_pages, block_tables,
-                                         lengths, k_new=k_new, v_new=v_new,
-                                         k_scale=k_scale, v_scale=v_scale,
+                                         lengths, layer=layer, k_new=k_new,
+                                         v_new=v_new, k_scale=k_scale,
+                                         v_scale=v_scale,
                                          interpret=interpret)

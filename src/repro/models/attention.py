@@ -439,7 +439,8 @@ def decode_attention(p: Params, x: jax.Array, *, cfg, plan, env: AxisEnv,
                      cache: Dict[str, jax.Array], positions: jax.Array,
                      block_table: Optional[jax.Array] = None,
                      paged_kernel: str = "auto",
-                     block_s: int = 0
+                     block_s: int = 0,
+                     layer: Optional[jax.Array] = None
                      ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One-token generation step against the KV cache.
 
@@ -454,7 +455,11 @@ def decode_attention(p: Params, x: jax.Array, *, cfg, plan, env: AxisEnv,
     activation vector against streamed weights + streamed KV.
 
     Paged mode (``block_table`` given): cache['k'/'v'] is the shared block
-    pool (N, kpr, bs, dh).  ``paged_kernel`` selects the dataflow:
+    pool of every layer, stacked (L, N, kpr, bs, dh) (scales (L, N, kpr,
+    bs)), and ``layer`` the one this call attends.  Nothing slices the
+    layer out: the kernel takes the stack and the layer index, so the
+    decode scan's carry is read in place.  ``paged_kernel`` selects the
+    dataflow:
 
     * ``"stream"`` — the Pallas paged kernel streams KV tiles straight
       from the pool via the scalar-prefetched block table; the new
@@ -512,13 +517,13 @@ def decode_attention(p: Params, x: jax.Array, *, cfg, plan, env: AxisEnv,
                        "mask": jnp.ones(positions.shape, bool)}
         if mode == "stream":
             out = paged_decode_attention(
-                q[:, 0], kc, vc, block_table, positions,
+                q[:, 0], kc, vc, block_table, positions, layer=layer,
                 k_new=k_fold[:, 0], v_new=v_fold[:, 0],
                 k_scale=cache.get("k_scale"),
                 v_scale=cache.get("v_scale"))[:, None]
             return out_proj(p, out, env, plan), updates
         kc, vc = _gather_pool_view(kc, vc, cache.get("k_scale"),
-                                   cache.get("v_scale"), block_table)
+                                   cache.get("v_scale"), block_table, layer)
         kmap = local_kmap(plan, env)
         out = _flash_decode_chunked(q, kc, vc, kmap,
                                     kv_valid_len=positions,
@@ -678,17 +683,19 @@ def _flash_decode_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def _gather_pool_view(kc: jax.Array, vc: jax.Array,
                       ks: Optional[jax.Array], vs: Optional[jax.Array],
-                      tables: jax.Array) -> Tuple[jax.Array, jax.Array]:
+                      tables: jax.Array, layer: Optional[jax.Array] = None
+                      ) -> Tuple[jax.Array, jax.Array]:
     """The gather oracle's contiguous (B, T*bs, kpr, dh) K/V view of the
     head-major pool through ``tables`` (B, T), dequantized when the pool
-    carries scale side-arrays ``ks``/``vs``."""
-    kview = gather_kv_pages(kc, tables)
-    vview = gather_kv_pages(vc, tables)
+    carries scale side-arrays ``ks``/``vs``.  ``layer``: the pools are
+    the layers' stacks and the view is of that layer."""
+    kview = gather_kv_pages(kc, tables, layer)
+    vview = gather_kv_pages(vc, tables, layer)
     if ks is not None:
         kview = kview.astype(jnp.float32) * \
-            gather_kv_pages(ks, tables).astype(jnp.float32)[..., None]
+            gather_kv_pages(ks, tables, layer).astype(jnp.float32)[..., None]
         vview = vview.astype(jnp.float32) * \
-            gather_kv_pages(vs, tables).astype(jnp.float32)[..., None]
+            gather_kv_pages(vs, tables, layer).astype(jnp.float32)[..., None]
     return kview, vview
 
 

@@ -228,9 +228,13 @@ def apply_layer(p: Params, x: jax.Array, *, cfg, plan, env: AxisEnv,
                 cache: Optional[Params] = None,
                 block_tables: Optional[jax.Array] = None,
                 paged_kernel: str = "auto", block_s: int = 0,
-                kv_valid_len: Optional[jax.Array] = None
+                kv_valid_len: Optional[jax.Array] = None,
+                layer: Optional[jax.Array] = None
                 ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
     """Returns (x, new_cache, aux_loss).
+
+    ``layer`` (decode over the paged pool): ``cache`` holds every super
+    block's pool stacked and this is the super block's index in it.
 
     ``mode="chunk_prefill"`` (attention-only stacks) prefills one chunk
     of a partially-resident prompt straight against the paged pool:
@@ -265,7 +269,7 @@ def apply_layer(p: Params, x: jax.Array, *, cfg, plan, env: AxisEnv,
                 p["attn"], h_in, cfg=cfg, plan=plan, env=env,
                 cache=cache, positions=positions,
                 block_table=block_tables, paged_kernel=paged_kernel,
-                block_s=block_s)
+                block_s=block_s, layer=layer)
             new_cache = kv
         elif mode == "prefill":
             h, kv = attn_mod.prefill_attention(
@@ -314,7 +318,8 @@ def apply_super_block(p: Params, x: jax.Array, *, cfg, plan, env: AxisEnv,
                       cache: Optional[Params] = None,
                       block_tables: Optional[jax.Array] = None,
                       paged_kernel: str = "auto", block_s: int = 0,
-                      kv_valid_len: Optional[jax.Array] = None):
+                      kv_valid_len: Optional[jax.Array] = None,
+                      layer: Optional[jax.Array] = None):
     sb = super_block_size(cfg)
     aux_total = jnp.float32(0.0)
     new_cache: Dict[str, Any] = {}
@@ -326,7 +331,7 @@ def apply_super_block(p: Params, x: jax.Array, *, cfg, plan, env: AxisEnv,
                                   block_tables=block_tables,
                                   paged_kernel=paged_kernel,
                                   block_s=block_s,
-                                  kv_valid_len=kv_valid_len)
+                                  kv_valid_len=kv_valid_len, layer=layer)
         if cache is not None:
             new_cache[f"l{j}"] = cj2
         aux_total = aux_total + aux
@@ -351,26 +356,26 @@ def _scatter_cache_updates(cache_st, upd, idx, seq_sharded: bool,
                 # logical position ``pos`` lands in physical block
                 # table[b, pos // bs] at offset pos % bs of every head.
                 # Inactive slots point at the null block 0 (don't-care
-                # writes).
-                bs_blk = c["k"].shape[3]
+                # writes).  The heads are an index of the write, not a
+                # window of it: each update is one (dh,) row of one
+                # (block, head) plane, which fits the layout the kernel
+                # reads the carry in, so XLA keeps the carry there; a
+                # (gp, dh) window spanning heads moves the carry to
+                # another layout and converts the whole pool around the
+                # layer loop.
+                gp, bs_blk = c["k"].shape[2:4]
                 blk = jnp.take_along_axis(
-                    block_tables, (pos // bs_blk)[:, None], axis=1)[:, 0]
-                off = pos % bs_blk
-                out[lj] = {
-                    "k": c["k"].at[idx, blk, :, off].set(
-                        knew[:, 0].astype(c["k"].dtype)),
-                    "v": c["v"].at[idx, blk, :, off].set(
-                        vnew[:, 0].astype(c["v"].dtype)),
-                }
+                    block_tables, (pos // bs_blk)[:, None], axis=1)
+                at = (idx, blk, jnp.arange(gp)[None], (pos % bs_blk)[:, None])
+
+                def put(leaf, rows):
+                    return leaf.at[at].set(rows[:, 0].astype(leaf.dtype))
+                out[lj] = {"k": put(c["k"], knew), "v": put(c["v"], vnew)}
                 if "k_scale_new" in u:
                     # quantized pool: the row's absmax scales land beside
                     # the int8/fp8 values at the same (block, offset)
-                    out[lj]["k_scale"] = c["k_scale"].at[
-                        idx, blk, :, off].set(u["k_scale_new"][:, 0].astype(
-                            c["k_scale"].dtype))
-                    out[lj]["v_scale"] = c["v_scale"].at[
-                        idx, blk, :, off].set(u["v_scale_new"][:, 0].astype(
-                            c["v_scale"].dtype))
+                    out[lj]["k_scale"] = put(c["k_scale"], u["k_scale_new"])
+                    out[lj]["v_scale"] = put(c["v_scale"], u["v_scale_new"])
             elif seq_sharded and c["k"].ndim == 6:
                 old_k = c["k"][idx, b_idx, 0, pos]
                 old_v = c["v"][idx, b_idx, 0, pos]
@@ -479,14 +484,20 @@ def forward(params: Params, tokens: jax.Array, *, cfg, plan, env: AxisEnv,
             xc, auxc, cache_st = carry
             bp, idx = xs
             bp = gather_fn("block", bp)
-            sl = jax.tree.map(
-                lambda a: lax.dynamic_index_in_dim(a, idx, 0,
-                                                   keepdims=False),
-                cache_st)
+            if block_tables is None:
+                sl = jax.tree.map(
+                    lambda a: lax.dynamic_index_in_dim(a, idx, 0,
+                                                       keepdims=False),
+                    cache_st)
+                layer = None
+            else:
+                # the paged pool is read in place: attention takes the
+                # stacked carry and the layer index, never a slice
+                sl, layer = cache_st, idx
             xc, upd, aux = apply_super_block(
                 bp, xc, cfg=cfg, plan=plan, env=env, positions=positions,
                 mode=mode, cache=sl, block_tables=block_tables,
-                paged_kernel=paged_kernel, block_s=block_s)
+                paged_kernel=paged_kernel, block_s=block_s, layer=layer)
             cache_st = _scatter_cache_updates(cache_st, upd, idx,
                                               seq_sharded, block_tables)
             return (xc, auxc + aux, cache_st), None

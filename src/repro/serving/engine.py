@@ -1953,21 +1953,35 @@ class LPUEngine:
         dh = a.d_head if a is not None else LANE
         return plan_block_s(self.max_seq, dh, gs, self.kv_prec.itemsize)
 
-    def lower_decode_text(self) -> str:
-        """MLIR of the decode program this engine will actually run (the
-        fused 1-step window, or the host-sampled logits step) — the
-        bench's MEASURED no-copy gate greps this text for per-request
-        view tensors instead of trusting the analytic byte formula."""
+    def lower_decode(self, sharding=None):
+        """The decode program this engine will actually run (the fused
+        1-step window, or the host-sampled logits step), lowered.
+
+        ``sharding``: lower for that device instead, with every argument
+        abstract (shapes and dtypes of the engine's own), e.g. a
+        described TPU's device for an ahead-of-time compile."""
         tables = (jnp.asarray(self.block_tables) if self.paged else None)
         if self.sampling != "fused":
-            toks = jnp.zeros((self.slots, 1), jnp.int32)
-            pos = jnp.zeros((self.slots,), jnp.int32)
-            return self._decode.lower(self.params, self.cache, toks, pos,
-                                      tables).as_text()
-        carry, samp = self._slot_state()
-        return self._window(1).lower(self.params, self.cache, tables,
-                                     *carry, self.rng, *samp,
-                                     np.bool_(False)).as_text()
+            fn = self._decode
+            args = (self.params, self.cache,
+                    jnp.zeros((self.slots, 1), jnp.int32),
+                    jnp.zeros((self.slots,), jnp.int32), tables)
+        else:
+            carry, samp = self._slot_state()
+            fn = self._window(1)
+            args = (self.params, self.cache, tables, *carry, self.rng,
+                    *samp, np.bool_(False))
+        if sharding is not None:
+            args = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                               sharding=sharding), args)
+        return fn.lower(*args)
+
+    def lower_decode_text(self) -> str:
+        """MLIR of :meth:`lower_decode` — the bench's MEASURED no-copy
+        gate greps this text for per-request view tensors instead of
+        trusting the analytic byte formula."""
+        return self.lower_decode().as_text()
 
 
 class MultiRingEngine:

@@ -48,13 +48,21 @@ def greedy_ref(tiny_model):
 # -- greedy bit-parity with the pre-fusion engine ----------------------
 
 @pytest.mark.parametrize("steps", [1, 4])
-@pytest.mark.parametrize("kern", ["dense", "stream", "gather"])
+@pytest.mark.parametrize("kern", ["dense", "stream", "gather",
+                                  "stream-b128"])
 def test_fused_greedy_matches_host(tiny_model, greedy_ref, kern, steps):
+    """``stream-b128``: 128-token blocks of 32-wide heads, which the
+    stacked-pool kernel reads as (dh, bs) planes."""
     model, params = tiny_model
-    kw = (dict(paged=False) if kern == "dense"
-          else dict(paged=True, block_size=16, paged_kernel=kern))
-    eng = LPUEngine(model, params, slots=3, max_seq=64,
-                    steps_per_sync=steps, **kw)
+    kw = dict(max_seq=64)
+    if kern == "dense":
+        kw.update(paged=False)
+    elif kern == "stream-b128":
+        kw.update(paged=True, max_seq=128, block_size=128,
+                  paged_kernel="stream")
+    else:
+        kw.update(paged=True, block_size=16, paged_kernel=kern)
+    eng = LPUEngine(model, params, slots=3, steps_per_sync=steps, **kw)
     assert eng.generate(PROMPTS, max_new_tokens=10) == greedy_ref
     # the fused engine never reads a logits row: O(slots) bytes/token
     assert eng.stats.bytes_to_host_per_token <= 8 * eng.slots + 16

@@ -68,6 +68,57 @@ def test_kernel_fold_fallback_matches_pallas():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("use_pallas", [True, False],
+                         ids=["kernel", "gather"])
+@pytest.mark.parametrize("dh", [64, 128])
+@pytest.mark.parametrize("store", ["bfloat16", "int8"])
+def test_stacked_pool_matches_sliced_layer(store, dh, use_pallas):
+    """Layer ``l`` of a stacked (L, N, G, bs, dh) pool, read through the
+    layer index, equals the 4-D call on ``pool[l]`` bit for bit — the
+    kernel (at d_head 64 with block 128 through the (dh, bs) planes the
+    TPU stores, at d_head 128 through (bs, dh) ones) and the gather
+    oracle, with an int8 pool's stacked scales too."""
+    from repro.kernels.decode_attention.decode_attention import \
+        lane_dense_planes
+    from repro.serving.kv_cache import quantize_kv_rows
+    L, N, G, H, bs, B = 3, 7, 2, 4, 128, 3
+    assert lane_dense_planes(bs, dh) == (dh == 64)
+    ks = jax.random.split(jax.random.PRNGKey(dh), 5)
+    kp = jax.random.normal(ks[0], (L, N, G, bs, dh), jnp.float32)
+    vp = jax.random.normal(ks[1], (L, N, G, bs, dh), jnp.float32)
+    if store == "int8":
+        kp, k_sc = quantize_kv_rows(kp, jnp.int8, jnp.bfloat16)
+        vp, v_sc = quantize_kv_rows(vp, jnp.int8, jnp.bfloat16)
+        scales = {"k_scale": k_sc, "v_scale": v_sc}
+    else:
+        kp, vp, scales = kp.astype(store), vp.astype(store), {}
+    q = jax.random.normal(ks[2], (B, H, dh), jnp.bfloat16)
+    kn = jax.random.normal(ks[3], (B, G, dh), jnp.bfloat16)
+    vn = jax.random.normal(ks[4], (B, G, dh), jnp.bfloat16)
+    tables = jnp.asarray([[1, 2], [3, 4], [5, 0]], jnp.int32)
+    lengths = jnp.asarray([200, 77, 5], jnp.int32)
+    for l in range(L):
+        stacked = paged_decode_attention(
+            q, kp, vp, tables, lengths, layer=jnp.int32(l), k_new=kn,
+            v_new=vn, use_pallas=use_pallas, **scales)
+        sliced = paged_decode_attention(
+            q, kp[l], vp[l], tables, lengths, k_new=kn, v_new=vn,
+            use_pallas=use_pallas, **{k: v[l] for k, v in scales.items()})
+        np.testing.assert_array_equal(np.asarray(stacked),
+                                      np.asarray(sliced))
+
+
+def test_pool_rank_picks_the_call():
+    """A stacked pool needs its layer index and a 4-D pool refuses one:
+    the rank of the pool is the whole dispatch."""
+    q, kp, vp, _, _, tables, lengths = _fold_inputs(jax.random.PRNGKey(2))
+    with pytest.raises(ValueError, match="layer index"):
+        paged_decode_attention(q, kp[None], vp[None], tables, lengths)
+    with pytest.raises(ValueError, match="layer index"):
+        paged_decode_attention(q, kp, vp, tables, lengths,
+                               layer=jnp.int32(0))
+
+
 # ---------------------------------------------------------------------------
 # layout gate: which plans may stream
 # ---------------------------------------------------------------------------
