@@ -5,14 +5,14 @@
 For each seed, one run of the cell at its own load (a short window, the
 mix's longest requests drained as in a benchmark run), then the plain
 float32 reference over a seeded sample of the served tokens, and the
-control over the same positions: the reference with every matrix
-product computed in int8 (``harness.opt``, ``control="int8"``), one step
-below the bfloat16 the configurations serve in.  The control stands in
-the program's place in the run's own comparison, so each of its runs
-has to come out not correct.  Prints, per seed, the program's widest
-served-logit gap (the lower reading) and the control's (the upper
-reading), then one JSON line with both lists; exits 1 where a control
-run came out correct.
+control over the same positions: the reference one precision below the
+one the configuration serves in, as its family picks it (for OPT every
+matrix product computed in int8, below the bfloat16 served).  The
+control stands in the program's place in the run's own comparison, so
+each of its runs has to come out not correct.  Prints, per seed, the
+program's widest served-logit gap (the lower reading) and the
+control's (the upper reading), then one JSON line with both lists;
+exits 1 where a control run came out correct.
 
 The benchmark's own runs never run the control.
 """
@@ -54,7 +54,7 @@ def main(argv=None) -> int:
     program, control, rows = [], [], []
     for s in (int(x) for x in args.seeds.split(",")):
         r = run_cell(cell, s, args.seconds, False, time.perf_counter(),
-                     control="int8")
+                     control=True)
         g, limit = r["checks"]["program_logit_gap"]
         c = r["checks"]["served_logit_gap"][0]
         n = r["checks"]["served_tokens_compared"][0]

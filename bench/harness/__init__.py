@@ -1,7 +1,9 @@
-"""The serving benchmark's harness: spec loading, traffic, weights, the
-plain reference, trace reduction and the run itself.
+"""The serving benchmark's harness: spec loading, traffic, the model
+families (weights, the plain reference, operation counts), trace
+reduction and the run itself.
 
-Everything that belongs to one configuration, traffic mix, per-layer
-metric or cell limit lives in a data file (or, for a metric, a small
-reader) of its own under ``bench/``; these modules find each by name.
+Everything that belongs to one configuration, model family, traffic
+mix, per-layer metric or cell limit lives in a file of its own under
+``bench/`` (a data file, a family module or a small reader); these
+modules find each by name.
 """

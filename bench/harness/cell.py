@@ -4,11 +4,12 @@ check.
 The timed path is the serving program as its CLI builds it
 (``plan_model`` -> ``build_model`` -> ``LPUEngine``), driven
 through ``AsyncFrontend`` by the cell's traffic.  The benchmark makes
-the weights from the seed (``harness.opt``), measures on the host clock
-with tracing off, and in a ``--trace 1`` run reads the per-layer
-metrics from its own records and the profiler's trace.  After the
-window it frees the program and holds a sample of the served tokens to
-the plain float32 reference.
+the weights from the seed (the configuration's family module,
+``harness/families/<family>.py``), measures on the host clock with
+tracing off, and in a ``--trace 1`` run reads the per-layer metrics
+from its own records and the profiler's trace.  After the window it
+frees the program and holds a sample of the served tokens to the
+family's plain float32 reference.
 """
 from __future__ import annotations
 
@@ -24,7 +25,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from harness import opt, traffic as tr
+from harness import traffic as tr
+from harness.families import seed_key
 from harness.spec import Cell, load_peaks, metric_reader
 
 MIN_SERVED_TOKENS = 400        # served tokens the correctness sample holds
@@ -106,6 +108,12 @@ class Run:
     def loop(self) -> str:
         return self.cell.traffic["loop"]
 
+    @property
+    def family(self):
+        """The configuration's family module: the readers' operation
+        and byte counts and the decode kernel's name."""
+        return self.cell.family
+
     def window_steps(self) -> List[StepRec]:
         return [s for s in self.steps if s.t0 >= self.t0
                 and s.t1 <= self.t_close]
@@ -130,31 +138,24 @@ def pctl(xs: List[float], q: float) -> float:
 def build(cell: Cell, seed: int, on_chip: bool):
     """The configuration's model, its weights (one jitted call from the
     seed, on the device, in the served dtype) and the engine, on one
-    chip."""
-    import dataclasses
+    chip, all as the configuration's family says."""
     import jax
     from repro.compiler.mapper import plan_model
-    from repro.configs import get_config
     from repro.models.registry import build_model
     from repro.serving.config import EngineConfig
     from repro.serving.engine import LPUEngine
 
-    conf = cell.config
-    d = opt.dims(conf["model"])
-    base = get_config(conf["arch"])
-    cfg = dataclasses.replace(
-        base, name=conf["name"], n_layers=d["L"], d_model=d["D"],
-        n_heads=d["H"], n_kv_heads=d["H"], d_head=d["dh"], d_ff=d["F"],
-        vocab_size=d["V"],
-        max_seq=d["P"])
+    conf, fam = cell.config, cell.family
+    d = fam.dims(conf["model"])
+    cfg = fam.arch_config(conf, d)
     plan = plan_model(cfg, None, (1,), "serve", esl_overlap=False,
                       remat="none")
     model = build_model(cfg, plan)
     template = jax.eval_shape(lambda k: model.init(k)[0],
                               jax.random.PRNGKey(0))
     dtype = template["embed"].dtype
-    params = jax.jit(lambda key: opt.program_params(
-        opt.canonical(key, d, dtype), d, template))(opt.seed_key(seed))
+    params = jax.jit(lambda key: fam.program_params(
+        fam.canonical(key, d, dtype), d, template))(seed_key(seed))
     e = conf["engine"]
     engine = LPUEngine(model, params,
                        EngineConfig(slots=e["slots"], max_seq=e["max_seq"],
@@ -392,7 +393,7 @@ def expected_tokens(rec: ReqRec, max_seq: int) -> int:
 
 
 def check(cell: Cell, run: Run, seed: int, d: Dict[str, int],
-          control: Optional[str] = None) -> Dict[str, dict]:
+          control: bool = False) -> Dict[str, dict]:
     """The numbers ``correct`` is decided by, each beside its limit.
 
     With ``control``, the control takes the program's place in
@@ -413,9 +414,10 @@ def check(cell: Cell, run: Run, seed: int, d: Dict[str, int],
     checks["sampled_requests"] = {"value": len(picked), "limit": 1,
                                   "at_least": True}
     if picked:
-        c = jax.jit(lambda k: opt.canonical(k, d))(opt.seed_key(seed))
+        fam = cell.family
+        c = jax.jit(lambda k: fam.canonical(k, d))(seed_key(seed))
         seqs = [(r.req.prompt, r.served) for r in picked]
-        g, gc_ = opt.served_gaps(c, d, seqs, control=control)
+        g, gc_ = fam.served_gaps(c, d, seqs, control=control)
         del c
         checks["served_tokens_compared"] = {"value": int(g.size),
                                             "limit": 1, "at_least": True}
@@ -463,7 +465,7 @@ def require_chips(chips: int) -> None:
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
              t_start: float, on_chip: bool = True,
-             control: Optional[str] = None) -> dict:
+             control: bool = False) -> dict:
     """One run; returns the result line (a dict).  With ``control``,
     the control stands in the program's place in the comparison
     (:func:`check`)."""

@@ -1,4 +1,6 @@
-"""OPT weights from a seed, and the plain float32 reference forward.
+"""The OPT family: weights from a seed, the program's configuration, the
+plain float32 reference forward, and the operations and bytes of a
+decode step (the contract is in ``harness/families/__init__.py``).
 
 The benchmark makes the weights itself, in a layout of its own (HF OPT's
 names and ``x @ W`` orientation, layers stacked), in one jitted call on
@@ -16,18 +18,27 @@ same table; with random weights that only renames rows).  The serving
 program has no out-projection bias, so the seeded out-projection bias
 is zero, in the program and in the reference alike.
 
-``control="int8"`` computes every matrix product in int8: the
-projections and the head from int8 weights (per output column) and
-int8 activations (per row), and attention from int8 queries, keys,
-probabilities and values (each scaled per vector along the contracted
-dimension); symmetric absmax throughout.  That is the step below the
-bfloat16 the configurations serve in.
+The control (``served_gaps(..., control=True)``; ``"int8"`` inside)
+computes every matrix product in int8: the projections and the head
+from int8 weights (per output column) and int8 activations (per row),
+and attention from int8 queries, keys, probabilities and values (each
+scaled per vector along the contracted dimension); symmetric absmax
+throughout.  That is the step below the bfloat16 the configurations
+serve in.
+
+Operations and bytes: ``ctx`` is the list of resident token counts of
+the live slots at one decode step (each slot attends its ``p`` resident
+tokens plus the new one).  Bytes are what the algorithm has to move,
+not what a kernel happens to move: weights once per step, the live KV
+blocks once per layer, and the new token's K and V written once.  The
+vocabulary is ``V`` rows (the program's padding is not work).
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 from functools import partial
-from typing import Dict, Optional
+from typing import Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +49,7 @@ HI = lax.Precision.HIGHEST
 LN_EPS = 1e-5
 BIAS_STD = 0.1        # biases and LayerNorm offsets: large enough that a
                       # path that drops one fails the comparison
+DECODE_KERNEL = "paged_decode_attention"
 
 
 def dims(model: dict) -> Dict[str, int]:
@@ -51,13 +63,14 @@ def dims(model: dict) -> Dict[str, int]:
     return d
 
 
-def seed_key(seed: int) -> jax.Array:
-    """A PRNG key from any non-negative integer (the low 31 bits seed
-    it, the rest are folded in)."""
-    if seed < 0:
-        raise ValueError("seed must be >= 0")
-    k = jax.random.PRNGKey(seed & 0x7FFFFFFF)
-    return jax.random.fold_in(k, (seed >> 31) & 0x7FFFFFFF)
+def arch_config(conf: dict, d: Dict[str, int]):
+    """The serving program's ``ArchConfig``: the registry's entry for
+    ``conf["arch"]`` at the configuration's sizes."""
+    from repro.configs import get_config
+    return dataclasses.replace(
+        get_config(conf["arch"]), name=conf["name"], n_layers=d["L"],
+        d_model=d["D"], n_heads=d["H"], n_kv_heads=d["H"], d_head=d["dh"],
+        d_ff=d["F"], vocab_size=d["V"], max_seq=d["P"])
 
 
 def _shapes(d: Dict[str, int]) -> Dict[str, tuple]:
@@ -239,7 +252,7 @@ def _gaps(ref_logits, other_logits, targets):
     return best - tgt, best - alt
 
 
-def served_gaps(c, d, seqs, control: Optional[str] = None):
+def served_gaps(c, d, seqs, control: bool = False):
     """For each ``(prompt, served)`` pair: the gap, at every served
     position, between the reference's best logit and its logit for the
     served token (teacher-forced on the served tokens).  With
@@ -261,11 +274,68 @@ def served_gaps(c, d, seqs, control: Optional[str] = None):
         targets[b, len(p) - 1:len(p) - 1 + len(s)] = s
     ref = _head(c, hidden(c, d, tokens, lens), control=None)[..., :V]
     other = ref
-    if control is not None:
-        other = _head(c, hidden(c, d, tokens, lens, control),
-                      control=control)[..., :V]
+    if control:
+        other = _head(c, hidden(c, d, tokens, lens, "int8"),
+                      control="int8")[..., :V]
     g_served, g_other = _gaps(ref, other, jnp.asarray(targets))
     sel = targets >= 0
     g_served = np.asarray(g_served)[sel]
-    g_other = np.asarray(g_other)[sel] if control is not None else None
+    g_other = np.asarray(g_other)[sel] if control else None
     return g_served, g_other
+
+
+# -- operations and bytes of a decode step ----------------------------------
+
+def weight_params(d: Dict[str, int]) -> int:
+    """Parameters one decode step streams (head included, position
+    table and embedding gather excluded: one row each)."""
+    L, D, F, V = d["L"], d["D"], d["F"], d["V"]
+    per_layer = 4 * D * D + 2 * D * F + 4 * D + F + D + 4 * D
+    return L * per_layer + V * D + 2 * D
+
+
+def matmul_params(d: Dict[str, int]) -> int:
+    """Parameters that take part in a multiply-add per token."""
+    L, D, F, V = d["L"], d["D"], d["F"], d["V"]
+    return L * (4 * D * D + 2 * D * F) + V * D
+
+
+def blocks(p: int, block: int) -> int:
+    return -(-p // block)
+
+
+def kv_read_bytes(d: Dict[str, int], ctx: Sequence[int], block: int,
+                  itemsize: int = 2) -> int:
+    """K and V of the live slots' resident blocks, every layer."""
+    per_tok = 2 * d["D"] * itemsize
+    return d["L"] * sum(blocks(p, block) * block for p in ctx) * per_tok
+
+
+def step_bytes(d: Dict[str, int], ctx: Sequence[int], block: int,
+               itemsize: int = 2) -> int:
+    """Bytes one decode step has to move from or to HBM."""
+    write = d["L"] * len(ctx) * 2 * d["D"] * itemsize
+    return (weight_params(d) * itemsize + kv_read_bytes(d, ctx, block,
+                                                        itemsize) + write)
+
+
+def step_flops(d: Dict[str, int], ctx: Sequence[int]) -> int:
+    """Model operations of one decode step (2 per multiply-add): the
+    projections, MLP and head for every live token, and attention over
+    ``p + 1`` positions (scores and weighted sum)."""
+    attn = sum(4 * (p + 1) * d["D"] for p in ctx) * d["L"]
+    return 2 * matmul_params(d) * len(ctx) + attn
+
+
+def paged_attn_bytes(d: Dict[str, int], ctx: Sequence[int], block: int,
+                     itemsize: int = 2) -> int:
+    """One layer of the paged decode kernel: the live slots' KV blocks,
+    and their queries and outputs."""
+    kv = sum(blocks(p, block) * block for p in ctx) * 2 * d["D"] * itemsize
+    return kv + 2 * len(ctx) * d["D"] * itemsize
+
+
+def paged_attn_flops(d: Dict[str, int], ctx: Sequence[int]) -> int:
+    """One layer of the paged decode kernel."""
+    return sum(4 * (p + 1) * d["D"] for p in ctx)
+

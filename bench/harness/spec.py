@@ -6,6 +6,9 @@ maps to a file of its own:
 
 * configuration ``<c>``  -> the ``file`` its entry gives
   (``bench/configs/<c>.json``);
+* family ``<f>`` (the configuration file's ``family`` key) ->
+  ``bench/harness/families/<f>.py``: the model's weights, reference and
+  operation counts (the contract is in ``harness/families/__init__.py``);
 * traffic mix ``<t>``    -> ``bench/traffic/<t>.json``;
 * cell ``<w>``           -> ``bench/limits/<w>.json`` (the correctness
   limit and the readings it was set from);
@@ -16,21 +19,24 @@ A metric with a ``workloads`` key applies to the cells it lists; an
 end-to-end metric without one to every cell, a per-layer metric without
 one to every cell that reports the end-to-end metric it ``moves``.
 
-A later cell or metric is added by adding files and entries; nothing
-here changes.
+A later cell, metric or model family is added by adding files and
+entries; nothing here changes.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
+import re
 from dataclasses import dataclass
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 from harness.traffic import check_mix
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 
 
 class SpecError(ValueError):
@@ -46,6 +52,7 @@ class Cell:
     limit: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+    family: ModuleType             # harness/families/<config's family>.py
 
 
 def load_json(path: Path) -> dict:
@@ -73,6 +80,10 @@ def resolve_cell(bm: dict, name: str, root: Path = ROOT,
     if w["config"] not in confs:
         raise SpecError(f"{name}: no configuration {w['config']!r}")
     config = load_json(root / confs[w["config"]]["file"])
+    if "family" not in config:
+        raise SpecError(f"{name}: configuration {w['config']!r} names no "
+                        "family")
+    family = load_family(config["family"], bench)
     traffic_path = bench / "traffic" / f"{w['traffic']}.json"
     if not traffic_path.is_file():
         raise SpecError(f"{name}: no traffic file {traffic_path}")
@@ -89,20 +100,41 @@ def resolve_cell(bm: dict, name: str, root: Path = ROOT,
     return Cell(name=name, chips=int(w["chips"]), config=config,
                 traffic=traffic, limit=load_json(limit_path), end_to_end=e2e,
                 per_layer=[m for m in bm["per_layer"]
-                           if _applies(m, name) and m["moves"] in moved])
+                           if _applies(m, name) and m["moves"] in moved],
+                family=family)
+
+
+def load_module(path: Path, prefix: str) -> ModuleType:
+    """The module at ``path`` (names may hold dots and dashes, so it is
+    loaded from its path, not imported by name)."""
+    spec = importlib.util.spec_from_file_location(
+        prefix + path.stem.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def metric_reader(name: str, bench: Path = BENCH) -> Callable:
-    """``read`` of ``bench/metrics/<name>.py`` (names may hold dots, so
-    the module is loaded from its path, not imported by name)."""
+    """``read`` of ``bench/metrics/<name>.py``."""
     path = bench / "metrics" / f"{name}.py"
     if not path.is_file():
         raise SpecError(f"no reader for metric {name!r} at {path}")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(path, "bench_metric_").read
+
+
+_FAMILIES: Dict[Path, ModuleType] = {}
+
+
+def load_family(name: str, bench: Path = BENCH) -> ModuleType:
+    """``bench/harness/families/<name>.py``, loaded once per process (its
+    jitted reference is then traced once)."""
+    path = bench / "harness" / "families" / f"{name}.py"
+    if not (isinstance(name, str) and NAME.match(name) and path.is_file()):
+        raise SpecError(f"no family {name!r} at {path}")
+    path = path.resolve()
+    if path not in _FAMILIES:
+        _FAMILIES[path] = load_module(path, "bench_family_")
+    return _FAMILIES[path]
 
 
 def load_peaks(device_kind: str, bench: Path = BENCH) -> Dict[str, float]:

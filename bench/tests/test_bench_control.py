@@ -10,8 +10,9 @@ import time
 import numpy as np
 import pytest
 
-from harness import cell as C, opt
-from harness.spec import BENCH, Cell, load_benchmark, load_json
+from harness import cell as C
+from harness.families import seed_key
+from harness.spec import BENCH, Cell, load_benchmark, load_family, load_json
 
 FIX = BENCH / "tests" / "fixtures"
 SMALL_OPEN = dict(load_json(FIX / "tiny-open.json"),
@@ -33,7 +34,8 @@ def small():
     conf["engine"].update(max_seq=128)
     return Cell(name="small", chips=1, config=conf, traffic=SMALL_OPEN,
                 limit={"served_logit_gap": LIMIT},
-                end_to_end=bm["end_to_end"], per_layer=bm["per_layer"])
+                end_to_end=bm["end_to_end"], per_layer=bm["per_layer"],
+                family=load_family(conf["family"]))
 
 
 @pytest.mark.parametrize("seed", [1, 3])
@@ -43,8 +45,10 @@ def test_control_fails_where_the_program_passes(small, seed):
     prompts = [rng.integers(2, d["V"], size=n).tolist()
                for n in (8, 20, 33, 47)]
     outs = engine.generate(prompts, max_new_tokens=64)
-    c = opt.canonical(opt.seed_key(seed), d)
-    g, gc = opt.served_gaps(c, d, list(zip(prompts, outs)), control="int8")
+    fam = small.family
+    c = fam.canonical(seed_key(seed), d)
+    g, gc = fam.served_gaps(c, d, list(zip(prompts, outs)),
+                            control=True)
     assert g.size == 256
     assert g.max() < LIMIT < gc.max()
     assert gc.max() > 3 * g.max()
@@ -52,7 +56,7 @@ def test_control_fails_where_the_program_passes(small, seed):
 
 def test_a_run_with_the_control_in_place_is_not_correct(small):
     r = C.run_cell(small, 5, 1.0, False, time.perf_counter(),
-                   on_chip=False, control="int8")
+                   on_chip=False, control=True)
     checks = r["checks"]
     assert r["correct"] is False, checks
     assert checks["program_logit_gap"][0] <= LIMIT

@@ -10,7 +10,7 @@ import time
 import pytest
 
 from harness import cell as C
-from harness.spec import BENCH, Cell, load_benchmark, load_json
+from harness.spec import BENCH, Cell, load_benchmark, load_family, load_json
 
 FIX = BENCH / "tests" / "fixtures"
 # the tiny model's sound runs read widest gaps of 0 to 0.06 in bf16
@@ -20,10 +20,12 @@ LIMIT = 0.25
 
 def tiny_cell(traffic: str) -> Cell:
     bm = load_benchmark()
-    return Cell(name="tiny", chips=1, config=load_json(FIX / "tiny-opt.json"),
+    conf = load_json(FIX / "tiny-opt.json")
+    return Cell(name="tiny", chips=1, config=conf,
                 traffic=load_json(FIX / f"{traffic}.json"),
                 limit={"served_logit_gap": LIMIT},
-                end_to_end=bm["end_to_end"], per_layer=bm["per_layer"])
+                end_to_end=bm["end_to_end"], per_layer=bm["per_layer"],
+                family=load_family(conf["family"]))
 
 
 def kv_unchanged(engine):

@@ -1,9 +1,10 @@
-"""Operation and byte counts against hand counts for OPT-1.3B."""
-from harness import flops_bytes as fb
-from harness.opt import dims
-from harness.spec import BENCH, load_json
+"""Operation and byte counts of the OPT family against hand counts for
+OPT-1.3B."""
+from harness.families import over_steps
+from harness.spec import BENCH, load_family, load_json
 
-D13 = dims(load_json(BENCH / "configs" / "opt-1.3b.json")["model"])
+fb = load_family("opt")
+D13 = fb.dims(load_json(BENCH / "configs" / "opt-1.3b.json")["model"])
 
 
 def test_opt13_weight_params_by_hand():
@@ -36,5 +37,5 @@ def test_paged_kernel_by_hand():
     assert fb.paged_attn_bytes(D13, ctx, 128) == \
         384 * 2 * 2048 * 2 + 2 * 2 * 2048 * 2
     assert fb.paged_attn_flops(D13, ctx) == 4 * 2048 * (129 + 130)
-    assert fb.over_steps(fb.paged_attn_flops, D13, [ctx, ctx]) == \
+    assert over_steps(fb.paged_attn_flops, D13, [ctx, ctx]) == \
         2 * fb.paged_attn_flops(D13, ctx)

@@ -6,33 +6,29 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from harness import opt
-from harness.spec import BENCH, load_json
+from harness.families import seed_key
+from harness.spec import BENCH, load_family, load_json
 
 
 TINY = load_json(BENCH / "tests" / "fixtures" / "tiny-opt.json")
+opt = load_family(TINY["family"])
 D = opt.dims(TINY["model"])
 
 
 @pytest.fixture(scope="module")
 def program():
-    import dataclasses
     from repro.compiler.mapper import plan_model
-    from repro.configs import get_config
     from repro.models.registry import build_model
     from repro.serving.config import EngineConfig
     from repro.serving.engine import LPUEngine
-    cfg = dataclasses.replace(
-        get_config("opt-1.3b"), n_layers=D["L"], d_model=D["D"],
-        n_heads=D["H"], n_kv_heads=D["H"], d_head=D["dh"], d_ff=D["F"],
-        vocab_size=D["V"], max_seq=D["P"])
+    cfg = opt.arch_config(TINY, D)
     plan = plan_model(cfg, None, (1,), "serve", esl_overlap=False,
                       remat="none", compute_dtype="float32",
                       param_dtype="float32")
     model = build_model(cfg, plan)
     template = jax.eval_shape(lambda k: model.init(k)[0],
                               jax.random.PRNGKey(0))
-    c = opt.canonical(opt.seed_key(2_500_000_001), D, jnp.float32)
+    c = opt.canonical(seed_key(2_500_000_001), D, jnp.float32)
     params = opt.program_params(c, D, template)
     e = TINY["engine"]
     eng = LPUEngine(model, params, EngineConfig(
@@ -76,14 +72,32 @@ def test_int8_control_departs_from_the_reference(program):
     rng = np.random.default_rng(3)
     seqs = [(rng.integers(2, D["V"], size=20).tolist(),
              rng.integers(2, D["V"], size=20).tolist()) for _ in range(4)]
-    g, gc = opt.served_gaps(c, D, seqs, control="int8")
+    g, gc = opt.served_gaps(c, D, seqs, control=True)
     assert gc.shape == g.shape
     assert gc.max() > 0
 
 
 def test_weights_depend_on_every_bit_of_the_seed():
-    a = opt.canonical(opt.seed_key(5), D)
-    b = opt.canonical(opt.seed_key(5 + 2 ** 31), D)
+    a = opt.canonical(seed_key(5), D)
+    b = opt.canonical(seed_key(5 + 2 ** 31), D)
     assert not np.array_equal(np.asarray(a["wq"]), np.asarray(b["wq"]))
     assert np.array_equal(np.asarray(a["bo"]), np.zeros(D["L"] * D["D"])
                           .reshape(D["L"], D["D"]))
+
+
+@pytest.mark.parametrize("conf", ["configs/opt-1.3b.json",
+                                  "configs/opt-6.7b-16l.json",
+                                  "tests/fixtures/tiny-opt.json"])
+def test_arch_config_is_the_one_the_harness_built_inline(conf):
+    """Field for field, the program configuration the harness built
+    in ``cell.build`` before the family module took it over."""
+    import dataclasses
+    from repro.configs import get_config
+    c = load_json(BENCH / conf)
+    d = opt.dims(c["model"])
+    inline = dataclasses.replace(
+        get_config(c["arch"]), name=c["name"], n_layers=d["L"],
+        d_model=d["D"], n_heads=d["H"], n_kv_heads=d["H"], d_head=d["dh"],
+        d_ff=d["F"], vocab_size=d["V"], max_seq=d["P"])
+    assert dataclasses.asdict(opt.arch_config(c, d)) == \
+        dataclasses.asdict(inline)

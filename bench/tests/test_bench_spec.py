@@ -59,6 +59,8 @@ def test_config_files_state_their_cut():
     for c in BM["configs"]:
         conf = spec.load_json(ROOT / c["file"])
         assert conf["name"] == c["name"]
+        assert (spec.BENCH / "harness" / "families"
+                / f"{conf['family']}.py").is_file()
         assert conf["source"] == c["source"]
         assert conf["reduced"] == c["reduced"]
         for k in ("assumed", "deployment", "engine", "model"):
@@ -91,6 +93,52 @@ def test_a_mix_with_an_unread_key_is_refused(tmp_path):
     with pytest.raises(spec.SpecError):
         spec.resolve_cell(spec.load_benchmark(root),
                           "opt-6.7b-16l.latency-b1", root)
+
+
+def copy_bench(tmp_path):
+    root = tmp_path / "repo"
+    shutil.copytree(ROOT / "bench", root / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", root / "BENCHMARK.json")
+    return root
+
+
+def set_family(root, config: str, family):
+    path = root / "bench" / "configs" / f"{config}.json"
+    conf = json.loads(path.read_text())
+    if family is None:
+        del conf["family"]
+    else:
+        conf["family"] = family
+    path.write_text(json.dumps(conf))
+
+
+@pytest.mark.parametrize("family", ["no-such-family", "../spec", None])
+def test_a_configuration_without_its_family_file_is_refused(tmp_path,
+                                                             family):
+    """An unknown family, a name that leads out of the families'
+    directory, or none at all: refused when the cell is resolved."""
+    root = copy_bench(tmp_path)
+    set_family(root, "opt-6.7b-16l", family)
+    with pytest.raises(spec.SpecError):
+        spec.resolve_cell(spec.load_benchmark(root),
+                          "opt-6.7b-16l.latency-b1", root)
+
+
+def test_a_new_family_is_a_new_file(tmp_path):
+    """A configuration that names a family added as a file of its own
+    gets that module as its cell's family; nothing else changes."""
+    root = copy_bench(tmp_path)
+    families = root / "bench" / "harness" / "families"
+    (families / "scratch-lm.py").write_text(
+        'DECODE_KERNEL = "scratch_decode_attention"\n')
+    set_family(root, "opt-6.7b-16l", "scratch-lm")
+    c = spec.resolve_cell(spec.load_benchmark(root),
+                          "opt-6.7b-16l.latency-b1", root)
+    assert c.family.DECODE_KERNEL == "scratch_decode_attention"
+    assert c.family is spec.load_family("scratch-lm", root / "bench")
+    assert spec.resolve_cell(BM, "opt-6.7b-16l.latency-b1", ROOT).family \
+        is spec.load_family("opt")
 
 
 def test_unknown_names_are_errors():

@@ -105,23 +105,64 @@ def test_recorded_decode_windows():
         "bench.engine_step", "bench.on_token", "host.outside_spans"}
 
 
-def test_recorded_roofline_and_step_shares_stay_under_peak():
-    from harness.opt import dims as opt_dims
-    from harness.spec import BENCH, load_json, metric_reader
+SHARES = ("kernel.paged_attn_roofline", "decode_step.mfu",
+          "decode_step.hbm_bw_share")
+# what the readers gave on the recorded trace when the counts were
+# OPT's own module, before the harness reached them through a family
+RECORDED_SHARES = {"kernel.paged_attn_roofline": 6.643746868464454,
+                   "decode_step.mfu": 0.34755093856444746,
+                   "decode_step.hbm_bw_share": 7.472116649455735}
+
+
+def recorded_run(family):
+    """A run over the recorded trace with 16 live slots for 2 steps,
+    counted by ``family``."""
+    from harness.spec import BENCH, load_family, load_json
     evs = recorded()
-    hi = max(e.end_ns for e in evs)
-    red = T.reduce(evs, 0.0, hi, 1)
+    red = T.reduce(evs, 0.0, max(e.end_ns for e in evs), 1)
+    conf = load_json(BENCH / "configs" / "opt-1.3b.json")
 
     class Run:
-        dims = opt_dims(load_json(BENCH / "configs" / "opt-1.3b.json")
-                        ["model"])
+        dims = load_family(conf["family"]).dims(conf["model"])
         trace = red
         block = 128
         peaks = load_json(BENCH / "peaks.json")["devices"]["TPU v5 lite"]
 
         def decode_ctx(self):
-            return [[300] * 16, [301] * 16]       # 16 live slots, 2 steps
-    for name in ("kernel.paged_attn_roofline", "decode_step.mfu",
-                 "decode_step.hbm_bw_share"):
-        v = metric_reader(name)(Run())
+            return [[300] * 16, [301] * 16]
+    Run.family = family
+    return Run()
+
+
+def test_recorded_roofline_and_step_shares_stay_under_peak():
+    from harness.spec import load_family, metric_reader
+    run = recorded_run(load_family("opt"))
+    for name in SHARES:
+        v = metric_reader(name)(run)
         assert 0 < v < 100, (name, v)
+        assert v == pytest.approx(RECORDED_SHARES[name], rel=0, abs=1e-9)
+
+
+@pytest.mark.parametrize("name", SHARES)
+def test_share_readers_count_with_the_run_family(name):
+    """A family whose operations and bytes are twice OPT's reads twice
+    OPT's share on the same trace: the readers take their counts from
+    the run's family and from nowhere else."""
+    from harness.spec import BENCH, load_family, load_module, metric_reader
+    double = load_module(BENCH / "tests" / "fixtures" / "family_double.py",
+                         "bench_test_")
+    read = metric_reader(name)
+    opt = read(recorded_run(load_family("opt")))
+    assert read(recorded_run(double)) == pytest.approx(2 * opt, rel=1e-12)
+
+
+@pytest.mark.parametrize("name", SHARES)
+def test_a_family_whose_kernel_never_runs_reads_nothing(name):
+    from types import SimpleNamespace
+
+    from harness.spec import load_family, metric_reader
+    opt = load_family("opt")
+    other = SimpleNamespace(**{k: getattr(opt, k) for k in (
+        "step_flops", "step_bytes", "paged_attn_flops", "paged_attn_bytes")},
+        DECODE_KERNEL="latent_decode_attention")
+    assert metric_reader(name)(recorded_run(other)) is None
